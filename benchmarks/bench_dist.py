@@ -1,11 +1,13 @@
 """Distributed wave-engine benchmark: the decentralized-scaling story on a
 virtual-device mesh (DESIGN.md §4).
 
-Needs more than one XLA device, so ``main()`` defaults
-``XLA_FLAGS=--xla_force_host_platform_device_count=8`` *before* importing
-jax (the device count is locked at jax init) — which is also why the
-``benchmarks.run dist`` block shells out to this module instead of calling
-into it.  Three sections, all through the ONE shared commit loop
+Needs more than one XLA device.  On a TPU host it runs over the chips
+(``jax.devices()``), in the process that holds them.  Held to the CPU
+(``JAX_PLATFORMS=cpu``), ``__main__`` defaults
+``XLA_FLAGS=--xla_force_host_platform_device_count=8`` before JAX
+initializes (the device count locks there) — which is also why the
+``benchmarks.run dist`` block runs this module as a child on the CPU.
+Three sections, all through the ONE shared commit loop
 (``engine.run_wave_on``) over a ``MeshSubstrate``:
 
 * **scaling** — goodput (committed txns/s) for every scheduler × node
@@ -427,7 +429,10 @@ def main(argv=None) -> Dict:
 
 
 if __name__ == "__main__":
-    # must precede the first jax import: device count is locked at init
-    os.environ.setdefault("XLA_FLAGS",
-                          "--xla_force_host_platform_device_count=8")
+    from repro.jaxenv import held_to_cpu
+    # virtual host devices stand in for chips only on the CPU; the flag
+    # must precede JAX's backend init, where the device count locks
+    if held_to_cpu():
+        os.environ.setdefault("XLA_FLAGS",
+                              "--xla_force_host_platform_device_count=8")
     main()

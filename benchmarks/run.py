@@ -16,6 +16,8 @@ import time
 
 import numpy as np
 
+from repro.jaxenv import enable_compile_cache, held_to_cpu
+
 
 def _csv(name: str, us: float, derived: str) -> None:
     print(f"{name},{us:.2f},{derived}", flush=True)
@@ -149,11 +151,11 @@ def _planner() -> None:
             f.write("\n")
 
 
-def _dist() -> None:
-    """Distributed wave engine on an 8-virtual-device mesh; also refreshes
-    BENCH_dist.json.  Runs in a child python: the XLA device count is locked
-    at jax init, and this process may already have initialized jax with one
-    device — only a fresh interpreter can see the forced 8."""
+def _dist_child() -> list:
+    """Run ``benchmarks.bench_dist`` on 8 virtual CPU devices in a child
+    python and return its CSV rows.  The CPU device count locks when JAX
+    initializes, so :func:`main` calls this before this process imports
+    JAX; the child then holds no chip that the parent needs."""
     import subprocess
 
     env = dict(os.environ)
@@ -168,9 +170,31 @@ def _dist() -> None:
     out = subprocess.run(args, env=env, capture_output=True, text=True)
     if out.returncode != 0:
         raise SystemExit(f"benchmarks.bench_dist failed:\n{out.stderr[-3000:]}")
-    for line in out.stdout.splitlines():
-        if line.startswith("dist/"):        # pass through the CSV rows
+    return [line for line in out.stdout.splitlines()
+            if line.startswith("dist/")]
+
+
+_DIST_ROWS: list = []  # rows of the CPU child, run before JAX was imported
+
+
+def _dist() -> None:
+    """Distributed wave engine over the node mesh; also refreshes
+    BENCH_dist.json.  On a TPU it runs in this process over
+    ``jax.devices()``: a chip belongs to one process.  On the CPU its rows
+    come from the virtual-device child that :func:`main` ran first."""
+    if held_to_cpu():
+        for line in _DIST_ROWS:
             print(line, flush=True)
+        return
+    import jax
+
+    from . import bench_dist
+    if jax.default_backend() != "tpu":
+        raise SystemExit("the dist block needs TPU devices, or "
+                         "JAX_PLATFORMS=cpu for virtual host devices")
+    report = bench_dist.run(smoke="--smoke" in _FLAGS)
+    bench_dist.write_report(report)
+    bench_dist.print_csv(report)
 
 
 def _kernel_micro() -> None:
@@ -256,11 +280,7 @@ def _roofline_headlines() -> None:
         _csv(f"roofline/engine/{r['sched']}/{r['backend']}", 0.0,
              f"flops={r['flops']:.3g} bytes={r['bytes']:.3g} "
              f"AI={r['arith_intensity']} platform={r['platform']}")
-    try:
-        rows = roofline.load()
-    except Exception:
-        return
-    for s in roofline.summary(rows):
+    for s in roofline.summary(roofline.load()):
         u = s["useful"]
         _csv(s["name"], s["bound_s"] * 1e6,
              f"dominant={s['dominant']} useful={u if u is None else round(u, 2)}")
@@ -287,6 +307,9 @@ def main(argv=None) -> None:
     unknown = [n for n in names if n not in BLOCKS]
     if unknown:
         raise SystemExit(f"unknown block(s) {unknown}; pick from {list(BLOCKS)}")
+    if "dist" in names and held_to_cpu():
+        _DIST_ROWS[:] = _dist_child()     # before this process imports JAX
+    enable_compile_cache()
     print("name,us_per_call,derived")
     for n in names:
         BLOCKS[n]()

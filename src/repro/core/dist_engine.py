@@ -42,7 +42,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.kernels import KernelConfig
@@ -166,11 +165,11 @@ def _wave_fn(mesh: Mesh, sched: str, skew: int, gc_track: bool,
                                    placement=_denorm_placement(p_own, p_slot))
         return (*st, *out, clk)
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         node_fn, mesh=mesh,
         in_specs=(P("node"),) * _N_STORE + (P(),) * (_N_WAVE + 7),
         out_specs=(P("node"),) * _N_STORE + (P(),) * (_N_OUT + 1),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(mapped) if jit else mapped
 
@@ -205,11 +204,11 @@ def _scan_fn(mesh: Mesh, sched: str, skew: int, gc_track: bool,
             (stacked, jnp.arange(1, W + 1, dtype=jnp.int32)))
         return (*st, *outs, clock)
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         node_fn, mesh=mesh,
         in_specs=(P("node"),) * _N_STORE + (P(),) * (_N_WAVE + 5),
         out_specs=(P("node"),) * _N_STORE + (P(),) * (_N_OUT + 1),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(mapped)
 
@@ -250,11 +249,11 @@ def _block_fn(mesh: Mesh, sched: str, skew: int, gc_track: bool,
             (stacked, wave_idx0 + jnp.arange(B, dtype=jnp.int32)))
         return (*st, *outs, clock)
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         node_fn, mesh=mesh,
         in_specs=(P("node"),) * _N_STORE + (P(),) * (_N_WAVE + 7),
         out_specs=(P("node"),) * _N_STORE + (P(),) * (_N_OUT + 1),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(mapped)
 
@@ -413,9 +412,9 @@ def run_workload_fused_dist(store: MVStore, waves, mesh: Mesh,
 
 @functools.lru_cache(maxsize=None)
 def _pmin_fn(mesh: Mesh):
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         lambda f: lax.pmin(jnp.min(f), "node"), mesh=mesh,
-        in_specs=P("node"), out_specs=P(), check_rep=False))
+        in_specs=P("node"), out_specs=P(), check_vma=False))
 
 
 def mesh_watermark(mesh: Mesh, node_floors) -> int:

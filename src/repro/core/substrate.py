@@ -69,8 +69,8 @@ def mesh_degrade_count() -> int:
 
 def effective_mesh_backend(kernels: KernelConfig | str | None = None) -> str:
     """Honest label for what the mesh path runs under this request:
-    the resolved backend spec, or ``"jnp (degraded from pallas)"`` when the
-    capability probe says compiled Mosaic cannot run in this process."""
+    the resolved backend spec, or ``"jnp (degraded from pallas)"`` when
+    this process runs on a platform Mosaic does not compile for."""
     cfg = resolve(kernels)
     if cfg.backend == "pallas" and not can_compile_pallas():
         return "jnp (degraded from pallas)" + ("+fused" if cfg.fused else "")
@@ -81,11 +81,11 @@ def mesh_kernels(kernels: KernelConfig | str | None = None) -> KernelConfig:
     """The config a ``MeshSubstrate`` will actually run.
 
     Per-shard local block shapes are static under shard_map, so compiled
-    Mosaic kernels are legal on the mesh path whenever the platform can
-    lower them at all — ``pallas`` now passes through when the
-    once-per-process capability probe (``kernels.can_compile_pallas``)
-    succeeds, and degrades to the bit-identical ``jnp`` reference ONLY when
-    it fails (e.g. the CPU backend, which has no Mosaic target).
+    Mosaic kernels are legal on the mesh path wherever Mosaic compiles at
+    all.  The choice is made by platform (``kernels.can_compile_pallas``):
+    on a TPU ``pallas`` passes through, so a kernel Mosaic refuses raises
+    where the mesh program compiles; on any other platform ``pallas``
+    degrades to the bit-identical ``jnp`` reference.
     ``pallas_interpret``/``jnp`` always pass through.  The mesh drivers
     normalize through this BEFORE using the config as a jit/lru cache key,
     so a degraded ``pallas`` request and a ``jnp`` request share one trace
@@ -102,11 +102,10 @@ def mesh_kernels(kernels: KernelConfig | str | None = None) -> KernelConfig:
             _degrade_warned = True
             warnings.warn(
                 "KernelConfig('pallas') degrades to the bit-identical 'jnp' "
-                "reference on the mesh path: the capability probe found no "
-                "compiled-Mosaic support in this process (CPU backend); "
-                "mesh results are correct but do not measure compiled "
-                "kernels — request 'pallas_interpret' or 'jnp' explicitly "
-                "to silence this",
+                "reference on the mesh path: Mosaic compiles only for a "
+                "TPU, and this process runs elsewhere; mesh results are "
+                "correct but do not measure compiled kernels — request "
+                "'pallas_interpret' or 'jnp' explicitly to silence this",
                 RuntimeWarning, stacklevel=2)
         return KernelConfig("jnp", fused=cfg.fused)
     return cfg

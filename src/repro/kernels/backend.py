@@ -12,9 +12,9 @@ different backends coexist in one process.
 
 Backends:
 
-  ``pallas``           Mosaic-compiled kernels (TPU).
-  ``pallas_interpret`` the same kernel bodies, interpreted (CPU fallback;
-                       how CI exercises the kernels — bit-identical to
+  ``pallas``           Mosaic-compiled kernels (TPU only).
+  ``pallas_interpret`` the same kernel bodies, interpreted (the CPU route;
+                       how the tests exercise the kernels — bit-identical to
                        ``pallas`` by construction).
   ``jnp``              pure-jnp references (``kernels.ref``) — the escape
                        hatch and the differential-test oracle.
@@ -42,7 +42,6 @@ static jit argument.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import os
 
 import jax
@@ -114,38 +113,15 @@ def resolve(spec=None) -> KernelConfig:
     return KernelConfig(spec)
 
 
-# ---------------------------------------------------------------------------
-# capability probe: can THIS process actually compile-and-run a Mosaic
-# Pallas kernel?  The mesh path (``substrate.mesh_kernels``) degrades
-# ``pallas`` to the bit-identical ``jnp`` reference only when this says no —
-# per-shard block shapes are static under shard_map, so compiled kernels are
-# legal whenever the platform can lower them at all.
-# ---------------------------------------------------------------------------
-
-
-@functools.lru_cache(maxsize=None)
 def can_compile_pallas() -> bool:
-    """True iff a non-interpret ``pl.pallas_call`` compiles AND runs here.
+    """True iff this process runs on a TPU, the only target Mosaic compiles
+    these kernels for.
 
-    Probes by executing a tiny aligned kernel once per process (cached).
-    On CPU this fails (Mosaic needs a TPU target), which is exactly the
-    signal the mesh dispatch uses to gate its explicit jnp fallback.
-    """
-    try:
-        import jax.numpy as jnp
-        from jax.experimental import pallas as pl
-
-        def _probe(x_ref, o_ref):
-            o_ref[...] = x_ref[...] + 1
-
-        out = pl.pallas_call(
-            _probe,
-            out_shape=jax.ShapeDtypeStruct((8, 128), jnp.int32),
-        )(jnp.zeros((8, 128), jnp.int32))
-        jax.block_until_ready(out)
-        return True
-    except Exception:
-        return False
+    Decided by platform, not by a trial compile: on a TPU a kernel that
+    Mosaic refuses raises where it is compiled, and is never mistaken for
+    a platform without Mosaic (``substrate.mesh_kernels`` degrades
+    ``pallas`` to ``jnp`` only where this is False)."""
+    return jax.default_backend() == "tpu"
 
 
 # ---------------------------------------------------------------------------

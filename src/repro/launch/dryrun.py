@@ -21,10 +21,28 @@ from repro.launch.train import (abstract_train_state, make_decode_step,
 from repro.models.module import (abstract, param_shardings, use_mesh_and_rules)
 from repro.optim import adamw_init
 
-# TPU v5e-class hardware constants (roofline denominators)
-PEAK_FLOPS = 197e12        # bf16 FLOP/s per chip
-HBM_BW = 819e9             # bytes/s per chip
-ICI_BW = 50e9              # bytes/s per link
+# Published per-chip peaks (roofline denominators), keyed by ``device_kind``
+# as JAX reports it.  Source: Google Cloud documentation, "TPU v5e":
+# 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of chip-to-chip
+# interconnect over four links (50 GB/s each).
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
+}
+
+# The chip the dry-run meshes model (a v5e pod slice is 16x16).  The
+# compile itself runs on virtual host devices, whose kind says nothing
+# about the target, so the target is named here.
+TARGET_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> Dict[str, float]:
+    """Per-chip peaks of ``device_kind``; a kind not in ``PEAKS`` is an
+    error, never a default."""
+    if device_kind not in PEAKS:
+        raise KeyError(f"no published peaks for device kind {device_kind!r};"
+                       f" known: {sorted(PEAKS)}")
+    return PEAKS[device_kind]
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -217,16 +235,17 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     flops = hlo["flops"]
     bytes_acc = hlo["bytes"]
     mf = model_flops(cfg, shape)
-    compute_s = flops / PEAK_FLOPS
-    memory_s = bytes_acc / HBM_BW
-    collective_s = hlo["collective_bytes"] / ICI_BW
+    pk = peaks(TARGET_KIND)
+    compute_s = flops / pk["flops"]
+    memory_s = bytes_acc / pk["hbm_bw"]
+    collective_s = hlo["collective_bytes"] / pk["ici_bw"]
     dominant = max((("compute", compute_s), ("memory", memory_s),
                     ("collective", collective_s)), key=lambda kv: kv[1])[0]
 
     return {
         "arch": arch, "shape": shape_name,
         "mesh": "2x16x16" if multi_pod else "16x16",
-        "n_devices": n_dev,
+        "n_devices": n_dev, "target": TARGET_KIND,
         "kind": shape.kind,
         "lower_s": round(t_lower, 1), "compile_s": round(t_compile, 1),
         "memory": mem, "cost": cost, "collectives": coll,

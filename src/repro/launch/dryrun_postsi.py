@@ -20,8 +20,8 @@ import numpy as np
 from repro.core.dist_engine import dist_wave_traceable, make_node_mesh, shard_store
 from repro.core.workloads import micro_waves
 from repro.core.store import make_store
-from repro.launch.dryrun import (ICI_BW, PEAK_FLOPS, HBM_BW, _memory_analysis,
-                                 parse_collectives)
+from repro.launch.dryrun import (TARGET_KIND, _memory_analysis,
+                                 parse_collectives, peaks)
 from repro.launch.hlo_analysis import analyze
 
 
@@ -68,22 +68,22 @@ def main():
     hlo = analyze(txt, args.nodes)
     coll = parse_collectives(txt, args.nodes)
     mem = _memory_analysis(compiled)
+    pk = peaks(TARGET_KIND)
+    terms = {"compute": hlo["flops"] / pk["flops"],
+             "memory": hlo["bytes"] / pk["hbm_bw"],
+             "collective": hlo["collective_bytes"] / pk["ici_bw"]}
 
     rec = {
         "arch": "postsi-db", "shape": f"wave_T{args.txns}_O{args.ops}",
         "mesh": "16x16(node)", "n_devices": args.nodes,
-        "kind": "txn-wave",
+        "target": TARGET_KIND, "kind": "txn-wave",
         "lower_s": round(t_lower, 1), "compile_s": round(t_compile, 1),
         "memory": mem, "hlo": hlo, "collectives": coll,
         "roofline": {
-            "compute_s": hlo["flops"] / PEAK_FLOPS,
-            "memory_s": hlo["bytes"] / HBM_BW,
-            "collective_s": hlo["collective_bytes"] / ICI_BW,
-            "dominant": max(
-                (("compute", hlo["flops"] / PEAK_FLOPS),
-                 ("memory", hlo["bytes"] / HBM_BW),
-                 ("collective", hlo["collective_bytes"] / ICI_BW)),
-                key=lambda kv: kv[1])[0],
+            "compute_s": terms["compute"],
+            "memory_s": terms["memory"],
+            "collective_s": terms["collective"],
+            "dominant": max(terms, key=terms.get),
             "useful_flops_frac": None,
         },
     }

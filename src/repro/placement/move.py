@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.store import MVStore, NO_TID
@@ -94,11 +93,11 @@ def _move_fn(mesh: Mesh):
                         .at[si].set(_EMPTY[name], mode="drop"))
         return tuple(out)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         node_fn, mesh=mesh,
         in_specs=(P("node"),) * _N_STORE + (P(), P()),
         out_specs=(P("node"),) * _N_STORE,
-        check_rep=False))
+        check_vma=False))
 
 
 def apply_move_mesh(store: MVStore, rec: MoveRecord, mesh: Mesh) -> MVStore:

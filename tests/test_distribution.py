@@ -101,14 +101,13 @@ def test_compressed_psum_and_elastic_reshard():
     print(_run(r"""
 import numpy as np, jax, jax.numpy as jnp, functools
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from repro.optim.compress import compressed_psum
 
 mesh = Mesh(np.array(jax.devices()[:4]).reshape(4,), ("pod",))
 x = jnp.asarray(np.random.RandomState(0).randn(4, 64), jnp.float32)
 
-@functools.partial(shard_map, mesh=mesh, in_specs=P("pod"), out_specs=P("pod"),
-                   check_rep=False)
+@functools.partial(jax.shard_map, mesh=mesh, in_specs=P("pod"),
+                   out_specs=P("pod"), check_vma=False)
 def f(xs):
     total, err = compressed_psum(xs, "pod")
     return total
