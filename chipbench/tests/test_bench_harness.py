@@ -1,0 +1,222 @@
+"""The harness: its files, its refusals, and runs at a tiny size on the
+CPU, whole and with the timed path broken underneath."""
+import contextlib
+import json
+import os
+import re
+import shutil
+import subprocess
+import sys
+import time
+
+import pytest
+
+from chipbench import faults, harness
+
+ROOT = harness.ROOT
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
+TINY = {"scheduler": "postsi", "n_keys": 3072, "n_nodes": 8,
+        "n_versions": 8, "T": 16, "O": 4, "B": 4, "K": 2,
+        "mix": {"kind": "ycsb", "theta": 0.99, "read_frac": 0.5,
+                "dist_frac": 0.1, "n_ops": 4}}
+
+
+def test_benchmark_json_names_files_that_exist():
+    spec = harness.load_spec()
+    assert set(spec) == {"command", "paths", "run_seconds", "configs",
+                         "workloads", "end_to_end", "per_layer"}
+    assert spec["paths"] == ["chipbench"]
+    e2e = {m["name"]: m for m in spec["end_to_end"]}
+    cells = {w["name"]: w for w in spec["workloads"]}
+    for c in spec["configs"]:
+        assert NAME.match(c["name"]) and os.path.exists(
+            os.path.join(ROOT, c["file"]))
+        cfg = harness.load_config(c["name"])
+        assert cfg["name"] == c["name"]
+        assert set(c["reduced"]) <= set(cfg["reduced"])
+    for w in cells.values():
+        assert NAME.match(w["name"]) and NAME.match(w["traffic"])
+        harness.load_config(w["config"])
+        harness.load_traffic(w["traffic"])
+        reported = [m["name"] for m in harness.cell_metrics(
+            spec, w["name"], "end_to_end")]
+        assert "setup_s" in reported and len(reported) >= 2
+        assert harness.cell_metrics(spec, w["name"], "per_layer")
+    assert sum(w["chips"] == 4 for w in cells.values()) <= 1
+    for m in spec["per_layer"]:
+        assert NAME.match(m["name"])
+        harness.metric_reader(m["name"])
+        for w in m["workloads"]:
+            assert m["moves"] in [x["name"] for x in harness.cell_metrics(
+                spec, w, "end_to_end")]
+    for m in spec["end_to_end"]:
+        assert 0.01 <= m["bound"] <= 0.25
+
+
+@pytest.fixture(scope="module")
+def tiny_root(tmp_path_factory):
+    """A copy of the benchmark with tiny cells added as new files only."""
+    root = tmp_path_factory.mktemp("checkout")
+    shutil.copytree(os.path.join(ROOT, "chipbench"), root / "chipbench",
+                    ignore=shutil.ignore_patterns("__pycache__", "tests"))
+    spec = harness.load_spec()
+    for name, mix in (("tiny_ycsb", TINY["mix"]),
+                      ("tiny_bank", {"kind": "smallbank",
+                                     "weights": [0, 15, 15, 25, 15, 15],
+                                     "n_ops": 4})):
+        (root / "chipbench/configs" / f"{name}.json").write_text(
+            json.dumps(dict(TINY, name=name, mix=mix)))
+    (root / "chipbench/traffic/tiny_closed.json").write_text(json.dumps(
+        {"loop": "closed", "clients": 128, "warmup_s": 0.3}))
+    (root / "chipbench/traffic/tiny_open.json").write_text(json.dumps(
+        {"loop": "open", "rate_txn_s": 400, "warmup_s": 0.3}))
+    (root / "chipbench/metrics/commits_per_wave.py").write_text(
+        "def read(ctx):\n"
+        "    w = ctx.window\n"
+        "    return w['committed'] / w['waves'] if w['waves'] else None\n")
+    spec["workloads"] += [
+        {"name": "tiny.closed", "config": "tiny_ycsb",
+         "traffic": "tiny_closed", "chips": 1, "why": "test"},
+        {"name": "tiny_bank.closed", "config": "tiny_bank",
+         "traffic": "tiny_closed", "chips": 1, "why": "test"},
+        {"name": "tiny.open", "config": "tiny_ycsb",
+         "traffic": "tiny_open", "chips": 1, "why": "test"}]
+    spec["end_to_end"][0]["workloads"] = [w["name"]
+                                          for w in spec["workloads"]]
+    for m in spec["end_to_end"]:
+        if m["name"].startswith("commit_p"):
+            m["workloads"] = m["workloads"] + ["tiny.open"]
+    spec["per_layer"].append(
+        {"name": "commits_per_wave", "unit": "ratio", "better": "higher",
+         "source": "program_counter", "layer": "block program",
+         "moves": "goodput_txn_s",
+         "workloads": ["tiny.closed", "tiny_bank.closed", "tiny.open"]})
+    (root / "BENCHMARK.json").write_text(json.dumps(spec))
+    return str(root)
+
+
+def run(root, cell, trace=False, seconds=0.6, seed=2 ** 31 + 5):
+    spec = harness.load_spec(root)
+    return harness.run_cell(harness.find_cell(spec, cell), seed, seconds,
+                            trace, time.perf_counter(), spec,
+                            kernels="jnp", root=root)["line"]
+
+
+@pytest.mark.parametrize("cell", ["tiny.open", "tiny_bank.closed"])
+def test_new_files_are_picked_up_by_name(tiny_root, cell):
+    line = run(tiny_root, cell)
+    assert line["correct"] is True
+    assert line["metrics"]["goodput_txn_s"]["value"] > 0
+    assert list(line)[-1] == "checks"
+    assert all(c["limit"] == 0 for c in line["checks"].values())
+    assert line["device"]["platform"] == "cpu"
+
+
+def test_a_host_stall_delays_open_arrivals_and_sheds_none(tiny_root,
+                                                          monkeypatch):
+    """The host stands still for 0.5 s as the window opens: 200 arrivals
+    fall due meanwhile, three times what admission holds.  None is shed;
+    their wait shows in the tail."""
+    pump, calls = harness.Load.pump, []
+
+    def stalled(self, until, sending=True, stop=None):
+        calls.append(until)
+        if len(calls) == 2:
+            time.sleep(0.5)
+        return pump(self, until, sending, stop)
+
+    monkeypatch.setattr(harness.Load, "pump", stalled)
+    line = run(tiny_root, "tiny.open")
+    assert line["correct"] is True
+    assert line["failed"] == 0 and line["attempted"] > 200
+    assert line["metrics"]["commit_p95_ms"]["value"] > 250
+
+
+def test_a_new_per_layer_reader_is_read(tiny_root, monkeypatch):
+    spec = harness.load_spec(tiny_root)
+    names = [m["name"] for m in harness.cell_metrics(
+        spec, "tiny.closed", "per_layer")]
+    assert names == ["commits_per_wave"]
+    ctx = harness.Context(window={"committed": 30, "waves": 3})
+    assert harness.metric_reader("commits_per_wave", tiny_root)(ctx) == 10
+
+
+BROKEN = pytest.mark.parametrize("broken", [
+    None, faults.no_validation, faults.frozen_state, faults.half_batch,
+    faults.altered_value],
+    ids=["sound", "control", "frozen_state", "half_batch",
+         "altered_value"])
+
+
+def sees_the_fault(root, cell, broken):
+    with (broken() if broken else contextlib.nullcontext()):
+        line = run(root, cell)
+    assert line["correct"] is (broken is None)
+    failing = [k for k, c in line["checks"].items() if c["value"] > 0]
+    assert bool(failing) is (broken is not None)
+
+
+@BROKEN
+def test_correct_comes_out_false_on_a_broken_path(tiny_root, broken):
+    sees_the_fault(tiny_root, "tiny.closed", broken)
+
+
+@BROKEN
+def test_smallbank_comes_out_false_on_a_broken_path(tiny_root, broken):
+    sees_the_fault(tiny_root, "tiny_bank.closed", broken)
+
+
+MESH = r"""
+import json, sys, time
+sys.path[:0] = [sys.argv[1], sys.argv[1] + "/src"]
+from chipbench import faults, harness
+import contextlib
+spec = harness.load_spec(sys.argv[2])
+cell = dict(harness.find_cell(spec, "tiny.closed"), chips=4)
+for name in ("sound", "no_exchange"):
+    ctx = faults.no_exchange() if name != "sound" else contextlib.nullcontext()
+    with ctx:
+        line = harness.run_cell(cell, 11, 0.5, False, time.perf_counter(),
+                                spec, kernels="jnp", root=sys.argv[2])["line"]
+    print(json.dumps({name: [line["correct"], line["checks"]]}), flush=True)
+"""
+
+
+def test_mesh_exchange_left_out_is_caught(tiny_root):
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    out = subprocess.run([sys.executable, "-c", MESH, ROOT, tiny_root],
+                         env=env, capture_output=True, text=True,
+                         timeout=600)
+    assert out.returncode == 0, out.stderr[-3000:]
+    res = {}
+    for ln in out.stdout.splitlines():
+        res.update(json.loads(ln))
+    assert res["sound"][0] is True
+    assert res["sound"][1]["shard_devices"]["value"] == 0
+    assert res["no_exchange"][0] is False
+
+
+def _run_py(cwd, env):
+    return subprocess.run(
+        [sys.executable, "chipbench/run.py", "--workload", "smallbank.closed",
+         "--seed", "5", "--seconds", "1", "--trace", "0"],
+        cwd=cwd, env=env, capture_output=True, text=True, timeout=300)
+
+
+def test_refuses_to_run_without_a_tpu():
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    out = _run_py(ROOT, env)
+    assert out.returncode != 0
+    assert '"correct"' not in out.stdout
+    assert "no TPU" in out.stderr
+
+
+def test_refuses_to_run_without_the_program(tmp_path):
+    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), tmp_path)
+    shutil.copytree(os.path.join(ROOT, "chipbench"), tmp_path / "chipbench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
+    out = _run_py(str(tmp_path), dict(env, JAX_PLATFORMS="cpu"))
+    assert out.returncode != 0
+    assert '"correct"' not in out.stdout
