@@ -124,202 +124,217 @@ def run_wave_on(sub, store: MVStore, wave: Wave, wave_idx: jax.Array,
     clock0 = clock          # wave-entry clock = snapshot time for clocked scheds
     track_gc = gc_track or gc_block
     wm = clock if watermark is None else watermark
-    is_read = (wave.op_kind == READ) | (wave.op_kind == RMW)
-    is_write = (wave.op_kind == WRITE) | (wave.op_kind == RMW)
-    keys = wave.op_key
-    if placement is None:
-        pkeys = keys                                   # slot[k] == k
-    else:
-        nk = placement.slot.shape[0]
-        kc = jnp.clip(keys, 0, nk - 1)
-        # negative NOP sentinels pass through untranslated — the substrates'
-        # sentinel-drop / clamp handling must keep seeing them
-        pkeys = jnp.where(keys >= 0, placement.slot[kc], keys)
+    with jax.named_scope("read_phase"):
+        is_read = (wave.op_kind == READ) | (wave.op_kind == RMW)
+        is_write = (wave.op_kind == WRITE) | (wave.op_kind == RMW)
+        keys = wave.op_key
+        if placement is None:
+            pkeys = keys                                   # slot[k] == k
+        else:
+            nk = placement.slot.shape[0]
+            kc = jnp.clip(keys, 0, nk - 1)
+            # negative NOP sentinels pass through untranslated — the substrates'
+            # sentinel-drop / clamp handling must keep seeing them
+            pkeys = jnp.where(keys >= 0, placement.slot[kc], keys)
 
-    # ------------------------------------------------------------------ reads
-    if sched == "clocksi":
-        hs = host_skew if host_skew is not None else jnp.zeros((1,), jnp.int32)
-        my_skew = hs[wave.host]                                   # [T]
-        cutoff_wave = wave_idx - my_skew                          # snapshot wave
-        # visible: newest version whose wave tag < cutoff (stale snapshot)
-        key_wave, head_cid = sub.key_staleness(store, pkeys)      # [T,O] each
-        stale = key_wave >= cutoff_wave[:, None]
-        max_cid = jnp.where(stale, head_cid - 1, INF)
-    else:
-        max_cid = jnp.broadcast_to(jnp.int32(INF), keys.shape)
+        # ------------------------------------------------------------------ reads
+        if sched == "clocksi":
+            hs = host_skew if host_skew is not None else jnp.zeros((1,), jnp.int32)
+            my_skew = hs[wave.host]                                   # [T]
+            cutoff_wave = wave_idx - my_skew                          # snapshot wave
+            # visible: newest version whose wave tag < cutoff (stale snapshot)
+            key_wave, head_cid = sub.key_staleness(store, pkeys)      # [T,O] each
+            stale = key_wave >= cutoff_wave[:, None]
+            max_cid = jnp.where(stale, head_cid - 1, INF)
+        else:
+            max_cid = jnp.broadcast_to(jnp.int32(INF), keys.shape)
 
-    # the whole read phase — slot selection, the PostSI rule-3 seed (raise
-    # s_lo/c_lo to the CID of every version read) and the anti-dependency
-    # candidate build — is one substrate call, so the fused ``wave_commit``
-    # megakernel and the three-dispatch route swap under the engine without
-    # the rules seeing a difference (DESIGN.md §7)
-    # the potential matrix only needs key EQUALITY, which the injective slot
-    # map preserves — so building it over pkeys is identical to logical keys
-    (r_val, r_tid, r_cid, r_sid, r_slot, s_lo0,
-     potential) = sub.read_phase(store, pkeys, max_cid, is_read, is_write)
+        # the whole read phase — slot selection, the PostSI rule-3 seed (raise
+        # s_lo/c_lo to the CID of every version read) and the anti-dependency
+        # candidate build — is one substrate call, so the fused ``wave_commit``
+        # megakernel and the three-dispatch route swap under the engine without
+        # the rules seeing a difference (DESIGN.md §7)
+        # the potential matrix only needs key EQUALITY, which the injective slot
+        # map preserves — so building it over pkeys is identical to logical keys
+        (r_val, r_tid, r_cid, r_sid, r_slot, s_lo0,
+         potential) = sub.read_phase(store, pkeys, max_cid, is_read, is_write)
 
-    read_key = jnp.where(is_read, keys, -1)
-    read_cid = jnp.where(is_read, r_cid, -1)
-    c_lo0 = s_lo0
-    s_hi0 = jnp.full((T,), INF, jnp.int32)
+        read_key = jnp.where(is_read, keys, -1)
+        read_cid = jnp.where(is_read, r_cid, -1)
+        c_lo0 = s_lo0
+        s_hi0 = jnp.full((T,), INF, jnp.int32)
 
     # --------------------------------------------------------------- commits
     # deterministic commit order = wave-local index (tids ascend within wave)
     def commit_one(i, carry):
         (st, s_lo, s_hi, c_lo, status, s_arr, c_arr, wcid, clk, ev_cnt) = carry
-        active = status[i] == RUNNING
+        with jax.named_scope("newest"):
+            active = status[i] == RUNNING
+            k_i = keys[i]                                         # [O] logical
+            pk_i = pkeys[i]                                       # [O] physical
+            w_i = is_write[i]
+            r_i = is_read[i]
+            nv_val, nv_tid, nv_cid, nv_sid, nv_slot = sub.read_newest(st, pk_i)
+            # map newest creators to wave-local ids (or -1 if older wave)
+            local, creator_committed = creator_slots(nv_tid, wave.tid[0], T,
+                                                     status)
 
-        k_i = keys[i]                                             # [O] logical
-        pk_i = pkeys[i]                                           # [O] physical
-        w_i = is_write[i]
-        r_i = is_read[i]
-        nv_val, nv_tid, nv_cid, nv_sid, nv_slot = sub.read_newest(st, pk_i)
+        with jax.named_scope("validate"):
+            # lost update: an RMW whose read version is no longer newest
+            lost = lost_update(r_i, w_i, nv_cid, r_cid[i])
+            # CV rule 5(ii): newest creator has an rw edge from me (I read
+            # data it overwrote) -> it is invisible to me -> cannot overwrite
+            # its version
+            if sched in ("postsi", "cv"):
+                rw_to_creator = rw_edge_to_creator(
+                    w_i, local, creator_committed, potential[i])
+            else:
+                rw_to_creator = jnp.array(False)
 
-        # map newest creators to wave-local ids (or -1 if older wave)
-        local, creator_committed = creator_slots(nv_tid, wave.tid[0], T, status)
+            if sched in ("si", "dsi", "clocksi", "optimal"):
+                # first-committer-wins: any write over a same-wave commit
+                # aborts
+                ww_conc = (w_i & (local >= 0) & creator_committed).any()
+            else:  # postsi / cv may overwrite a committed peer (Fig.1 t2/t3)
+                ww_conc = jnp.array(False)
 
-        # lost update: an RMW whose read version is no longer newest
-        lost = lost_update(r_i, w_i, nv_cid, r_cid[i])
-        # CV rule 5(ii): newest creator has an rw edge from me (I read data it
-        # overwrote) -> it is invisible to me -> cannot overwrite its version
-        if sched in ("postsi", "cv"):
-            rw_to_creator = rw_edge_to_creator(w_i, local, creator_committed,
-                                               potential[i])
-        else:
-            rw_to_creator = jnp.array(False)
+            abort = lost | rw_to_creator | ww_conc
 
-        if sched in ("si", "dsi", "clocksi", "optimal"):
-            # first-committer-wins: any write over a same-wave commit aborts
-            ww_conc = (w_i & (local >= 0) & creator_committed).any()
-        else:  # postsi / cv allow overwriting a committed peer (Fig.1 t2/t3)
-            ww_conc = jnp.array(False)
+            if sched == "dsi":
+                # incremental snapshot: a *remote* read whose key was
+                # meanwhile overwritten implies a local/global timestamp
+                # mismatch -> abort
+                remote = node_of_key(k_i, n_nodes) != wave.host[i]
+                stale_remote = (r_i & remote & (nv_cid != r_cid[i])).any()
+                abort = abort | stale_remote
 
-        abort = lost | rw_to_creator | ww_conc
+            if sched == "postsi":
+                # rules 3/4(a)/5 (commit_phase.postsi_bounds); SIDs of read
+                # slots are re-gathered: peers may have bumped them while we
+                # ran
+                cur_sid = sub.read_sid(st, pk_i, r_slot[i])
+                ongoing_reader = ongoing_readers_of(i, potential, status)
+                s_i, c_i, iv_abort = postsi_bounds(
+                    s_lo[i], s_hi[i], c_lo[i], r_i, w_i, nv_cid, nv_sid,
+                    cur_sid, ongoing_reader, s_lo)
+                abort = abort | iv_abort
+            else:
+                # clocked baselines: snapshot = wave-entry clock; commit =
+                # clock++
+                s_i = clock0
+                c_i = clk + 1
 
-        if sched == "dsi":
-            # incremental snapshot: a *remote* read whose key was meanwhile
-            # overwritten implies a local/global timestamp mismatch -> abort
-            remote = node_of_key(k_i, n_nodes) != wave.host[i]
-            stale_remote = (r_i & remote & (nv_cid != r_cid[i])).any()
-            abort = abort | stale_remote
+            # GC watermark consult (DESIGN.md §8): does any write reuse a
+            # ring slot whose version is still visible above the watermark?
+            if track_gc:
+                evict_unsafe = w_i & sub.evicting_visible(st, pk_i, wm)  # [O]
+            if gc_block:
+                # blocked install: abort instead of corrupting still-visible
+                # reads; retried once the watermark passes the superseder
+                abort = abort | evict_unsafe.any()
 
-        if sched == "postsi":
-            # rules 3/4(a)/5 (commit_phase.postsi_bounds); SIDs of read slots
-            # are re-gathered: peers may have bumped them while we ran
-            cur_sid = sub.read_sid(st, pk_i, r_slot[i])
-            ongoing_reader = ongoing_readers_of(i, potential, status)
-            s_i, c_i, iv_abort = postsi_bounds(
-                s_lo[i], s_hi[i], c_lo[i], r_i, w_i, nv_cid, nv_sid, cur_sid,
-                ongoing_reader, s_lo)
-            abort = abort | iv_abort
-        else:
-            # clocked baselines: snapshot = wave-entry clock; commit = clock++
-            s_i = clock0
-            c_i = clk + 1
-
-        # GC watermark consult (DESIGN.md §8): does any write reuse a ring
-        # slot whose version is still visible above the watermark?
-        if track_gc:
-            evict_unsafe = w_i & sub.evicting_visible(st, pk_i, wm)   # [O]
-        if gc_block:
-            # blocked install: abort instead of corrupting still-visible
-            # reads; retried once the watermark passes the superseder
-            abort = abort | evict_unsafe.any()
-
-        commit = active & ~abort
-        new_status = jnp.where(active, jnp.where(abort, ABORTED, COMMITTED), status[i])
+            commit = active & ~abort
+            new_status = jnp.where(active, jnp.where(abort, ABORTED,
+                                                     COMMITTED), status[i])
 
         # ---- install writes (masked scatter; owner/OOB handling is the
         # substrate's concern: sentinel-drop locally, owner-only on the mesh)
-        wmask = w_i & commit
-        val_new = jnp.where(wave.op_kind[i] == RMW, r_val[i] + wave.op_val[i],
-                            wave.op_val[i])
-        st = sub.install(st, wmask, pk_i, val_new, wave.tid[i], c_i, wave_idx)
-        wcid = wcid.at[i].set(jnp.where(wmask, c_i, -1))
+        with jax.named_scope("install"):
+            wmask = w_i & commit
+            val_new = jnp.where(wave.op_kind[i] == RMW,
+                                r_val[i] + wave.op_val[i], wave.op_val[i])
+            st = sub.install(st, wmask, pk_i, val_new, wave.tid[i], c_i,
+                             wave_idx)
+            wcid = wcid.at[i].set(jnp.where(wmask, c_i, -1))
 
         # ---- rule 4(c): bump SIDs of read versions to my start time --------
         # guarded: skip if the ring slot was recycled since our wave-start read
-        st = sub.bump_sid(st, r_i & commit, pk_i, r_slot[i], r_tid[i], s_i)
+        with jax.named_scope("bump_sid"):
+            st = sub.bump_sid(st, r_i & commit, pk_i, r_slot[i], r_tid[i], s_i)
 
         # ---- rule 4(b): push bounds of conflicting *ongoing* transactions --
         if sched == "postsi":
-            s_lo, s_hi, c_lo = push_bounds(i, commit, s_i, c_i, potential,
-                                           status, s_lo, s_hi, c_lo)
+            with jax.named_scope("push_bounds"):
+                s_lo, s_hi, c_lo = push_bounds(i, commit, s_i, c_i, potential,
+                                               status, s_lo, s_hi, c_lo)
 
-        status = status.at[i].set(new_status)
-        s_arr = s_arr.at[i].set(jnp.where(commit, s_i, -1))
-        c_arr = c_arr.at[i].set(jnp.where(commit, c_i, -1))
-        clk = jnp.where(commit, jnp.maximum(clk, c_i), clk)
-        if track_gc:
-            ev_cnt = ev_cnt + jnp.where(
-                commit, evict_unsafe.astype(jnp.int32).sum(), 0)
+        with jax.named_scope("record"):
+            status = status.at[i].set(new_status)
+            s_arr = s_arr.at[i].set(jnp.where(commit, s_i, -1))
+            c_arr = c_arr.at[i].set(jnp.where(commit, c_i, -1))
+            clk = jnp.where(commit, jnp.maximum(clk, c_i), clk)
+            if track_gc:
+                ev_cnt = ev_cnt + jnp.where(
+                    commit, evict_unsafe.astype(jnp.int32).sum(), 0)
         return (st, s_lo, s_hi, c_lo, status, s_arr, c_arr, wcid, clk, ev_cnt)
 
-    status0 = jnp.full((T,), RUNNING, jnp.int32)
-    s0 = jnp.full((T,), -1, jnp.int32)
-    c0 = jnp.full((T,), -1, jnp.int32)
-    wcid0 = jnp.full((T, O), -1, jnp.int32)
+    with jax.named_scope("commit_loop"):
+        status0 = jnp.full((T,), RUNNING, jnp.int32)
+        s0 = jnp.full((T,), -1, jnp.int32)
+        c0 = jnp.full((T,), -1, jnp.int32)
+        wcid0 = jnp.full((T, O), -1, jnp.int32)
 
-    (store, s_lo, s_hi, c_lo, status, s_arr, c_arr, wcid, clock,
-     evicted) = lax.fori_loop(
-        0, T, commit_one,
-        (store, s_lo0, s_hi0, c_lo0, status0, s0, c0, wcid0, clock,
-         jnp.int32(0)))
+        (store, s_lo, s_hi, c_lo, status, s_arr, c_arr, wcid, clock,
+         evicted) = lax.fori_loop(
+            0, T, commit_one,
+            (store, s_lo0, s_hi0, c_lo0, status0, s0, c0, wcid0, clock,
+             jnp.int32(0)))
 
-    write_key = jnp.where(is_write & (status[:, None] == COMMITTED), keys, -1)
+        write_key = jnp.where(is_write & (status[:, None] == COMMITTED), keys, -1)
 
     # ------------------------------------------------------------------ stats
     # work delegation batches per (txn, remote node) pair (paper §IV-A), so
     # cross-node messages count DISTINCT remote nodes touched, not raw ops
-    MAX_NODES = 32
-    op_node = node_of_key(keys, n_nodes)                               # [T,O]
-    active_op = wave.op_kind != NOP
-    node_ids = jnp.arange(MAX_NODES)[None, None, :]
-    touch = (op_node[..., None] == node_ids) & active_op[..., None]    # [T,O,MN]
-    node_touched = touch.any(axis=1)                                   # [T,MN]
-    remote_mask = jnp.arange(MAX_NODES)[None, :] != wave.host[:, None]
-    remote_nodes = (node_touched & remote_mask)
-    msgs_cross = remote_nodes.sum()
-    remote_op = (op_node != wave.host[:, None]) & active_op
-    committed = status == COMMITTED
-    if sched == "postsi":
-        # negotiation: one message per DISTINCT peer host per committer
-        edge = potential & committed[None, :]
-        peer_host_hot = (wave.host[None, :, None] == node_ids) & edge[:, :, None]
-        peer_hosts = peer_host_hot.any(axis=1)                         # [T,MN]
-        cross_peer = peer_hosts & (jnp.arange(MAX_NODES)[None, :] != wave.host[:, None])
-        msgs_cross = msgs_cross + cross_peer.sum()
-        msgs_coord = jnp.int32(0)
-    elif sched == "cv":
-        # anti-dependency entries stored on both endpoint hosts (§IV-A):
-        # insertion crosses hosts like PostSI negotiation ...
-        edge = potential & committed[None, :]
-        peer_host_hot = (wave.host[None, :, None] == node_ids) & edge[:, :, None]
-        peer_hosts = peer_host_hot.any(axis=1)
-        cross_peer = peer_hosts & (jnp.arange(MAX_NODES)[None, :] != wave.host[:, None])
-        msgs_cross = msgs_cross + cross_peer.sum()
-        # ... and reads consult the table on remote hosts (paper §V-D):
-        # batched per (txn, remote node) visited for reading
-        read_touch = (op_node[..., None] == node_ids) & (is_read & active_op)[..., None]
-        read_nodes = (read_touch.any(axis=1) & remote_mask)
-        msgs_cross = msgs_cross + read_nodes.sum()
-        msgs_coord = jnp.int32(0)
-    elif sched == "si":
-        msgs_coord = jnp.int32(2 * T)                  # begin + end, per txn
-    elif sched == "dsi":
-        distributed = remote_op.any(axis=1)
-        msgs_coord = 2 * distributed.sum()             # global txns pay globally
-    elif sched == "clocksi":
-        msgs_coord = jnp.int32(0)
-    else:  # optimal
-        msgs_coord = jnp.int32(0)
+    with jax.named_scope("message_stats"):
+        MAX_NODES = 32
+        op_node = node_of_key(keys, n_nodes)                               # [T,O]
+        active_op = wave.op_kind != NOP
+        node_ids = jnp.arange(MAX_NODES)[None, None, :]
+        touch = (op_node[..., None] == node_ids) & active_op[..., None]    # [T,O,MN]
+        node_touched = touch.any(axis=1)                                   # [T,MN]
+        remote_mask = jnp.arange(MAX_NODES)[None, :] != wave.host[:, None]
+        remote_nodes = (node_touched & remote_mask)
+        msgs_cross = remote_nodes.sum()
+        remote_op = (op_node != wave.host[:, None]) & active_op
+        committed = status == COMMITTED
+        if sched == "postsi":
+            # negotiation: one message per DISTINCT peer host per committer
+            edge = potential & committed[None, :]
+            peer_host_hot = (wave.host[None, :, None] == node_ids) & edge[:, :, None]
+            peer_hosts = peer_host_hot.any(axis=1)                         # [T,MN]
+            cross_peer = peer_hosts & (jnp.arange(MAX_NODES)[None, :] != wave.host[:, None])
+            msgs_cross = msgs_cross + cross_peer.sum()
+            msgs_coord = jnp.int32(0)
+        elif sched == "cv":
+            # anti-dependency entries stored on both endpoint hosts (§IV-A):
+            # insertion crosses hosts like PostSI negotiation ...
+            edge = potential & committed[None, :]
+            peer_host_hot = (wave.host[None, :, None] == node_ids) & edge[:, :, None]
+            peer_hosts = peer_host_hot.any(axis=1)
+            cross_peer = peer_hosts & (jnp.arange(MAX_NODES)[None, :] != wave.host[:, None])
+            msgs_cross = msgs_cross + cross_peer.sum()
+            # ... and reads consult the table on remote hosts (paper §V-D):
+            # batched per (txn, remote node) visited for reading
+            read_touch = (op_node[..., None] == node_ids) & (is_read & active_op)[..., None]
+            read_nodes = (read_touch.any(axis=1) & remote_mask)
+            msgs_cross = msgs_cross + read_nodes.sum()
+            msgs_coord = jnp.int32(0)
+        elif sched == "si":
+            msgs_coord = jnp.int32(2 * T)                  # begin + end, per txn
+        elif sched == "dsi":
+            distributed = remote_op.any(axis=1)
+            msgs_coord = 2 * distributed.sum()             # global txns pay globally
+        elif sched == "clocksi":
+            msgs_coord = jnp.int32(0)
+        else:  # optimal
+            msgs_coord = jnp.int32(0)
 
-    waits = jnp.int32(0)
-    if sched == "clocksi" and host_skew is not None:
-        # ahead-snapshot reads on behind remote nodes must wait (paper §II)
-        node_skew = host_skew[node_of_key(keys, n_nodes)]
-        my_skew = host_skew[wave.host][:, None]
-        waits = jnp.maximum(node_skew - my_skew, 0).sum(where=remote_op & is_read)
+        waits = jnp.int32(0)
+        if sched == "clocksi" and host_skew is not None:
+            # ahead-snapshot reads on behind remote nodes must wait (paper §II)
+            node_skew = host_skew[node_of_key(keys, n_nodes)]
+            my_skew = host_skew[wave.host][:, None]
+            waits = jnp.maximum(node_skew - my_skew, 0).sum(where=remote_op & is_read)
 
     out = WaveOut(status, s_arr, c_arr, read_key, read_cid, write_key, wcid,
                   msgs_cross, msgs_coord, waits, evicted)

@@ -70,6 +70,10 @@ class TxnRequest:
     folded: List["TxnRequest"] = field(default_factory=list)
                                  # same-key RMW members riding this leader's
                                  # wave row; empty unless fold_rmw packed it
+    # wall-clock stamps (time.perf_counter seconds, -1.0 until set)
+    t_submit: float = -1.0       # entered TxnService.submit
+    t_dispatch: float = -1.0     # its committed execution's block dispatched
+    t_ack: float = -1.0          # its commit was routed
 
     @property
     def latency(self) -> int:

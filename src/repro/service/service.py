@@ -27,6 +27,7 @@ from __future__ import annotations
 import dataclasses
 import itertools
 import time
+from collections import defaultdict
 from typing import Callable, Dict, Iterable, List, Optional
 
 import jax.numpy as jnp
@@ -41,6 +42,7 @@ from repro.placement import (HotKeyReplicas, LoadBalancer, apply_move,
 
 from .former import TxnRequest, WaveFormer, fold_counts
 from .gc import VisibilityGC
+from .obs import record, stage
 from .retry import RetryPolicy
 
 
@@ -61,7 +63,7 @@ class ServiceReport:
     executions: int        # total txn slots executed (incl. retries)
     waves: int
     idle_ticks: int
-    wall_s: float
+    wall_s: float          # host seconds in tick, flush and step stages
     txns_per_sec: float    # sustained executed txns/sec (wall)
     goodput_tps: float     # committed txns/sec (wall)
     retry_rate: float      # retries / admitted
@@ -88,6 +90,9 @@ class ServiceReport:
     tenants: Dict[str, Dict] = dataclasses.field(default_factory=dict)
     fold_groups: int = 0         # wave rows that carried a same-key RMW fold
     folded_requests: int = 0     # member requests that rode those rows free
+    # host stage timers (service/obs.py): seconds and count per stage
+    stage_s: Dict[str, float] = dataclasses.field(default_factory=dict)
+    stage_n: Dict[str, int] = dataclasses.field(default_factory=dict)
 
     def as_dict(self) -> Dict:
         d = dataclasses.asdict(self)
@@ -191,7 +196,8 @@ class TxnService:
         self.idle_ticks = 0
         self.latencies: List[int] = []
         self._req_ids = itertools.count(1)
-        self._wall_s = 0.0
+        self.stage_s: Dict[str, float] = defaultdict(float)   # obs.stage
+        self.stage_n: Dict[str, int] = defaultdict(int)
         self.stream = None                   # StreamingDriver, when serving
         self._last_dispatch = (0, None)      # (wave_idx0, wm) of last block
         self.base_store = None    # snapshot rings when history is a suffix
@@ -232,10 +238,17 @@ class TxnService:
         carries its fate (``rejected`` immediately, else async).  ``tenant``
         selects the admission/fairness class (DESIGN.md §12) — untagged
         submits share the default tenant 0."""
+        t0 = time.perf_counter()
+        req = self._admit(op_kind, op_key, op_val, host, tenant, t0)
+        record(self, "submit", t0)
+        return req
+
+    def _admit(self, op_kind, op_key, op_val, host, tenant,
+               t_submit: float) -> TxnRequest:
         req = TxnRequest(next(self._req_ids), np.asarray(op_kind, np.int32),
                          np.asarray(op_key, np.int32),
                          np.asarray(op_val, np.int32), int(host),
-                         tenant=int(tenant))
+                         tenant=int(tenant), t_submit=t_submit)
         self.requests.append(req)
         self._tstat(req.tenant)["offered"] += 1
         if (self.replicas is not None
@@ -270,7 +283,10 @@ class TxnService:
         """One scheduler tick: form a wave, execute it, route outcomes.
         Returns the numpy ``WaveOut`` or ``None`` for an idle tick."""
         self.tick += 1
-        t0 = time.perf_counter()
+        with stage(self, "step"):
+            return self._step()
+
+    def _step(self):
         if (self.replicas is not None
                 and self.tick % self.replica_refresh == 0):
             self._refresh_replicas()
@@ -280,13 +296,12 @@ class TxnService:
             return None
         wave, slots = formed
         if self.planner is not None and self.planner.planned:
-            out = self._step_planned(wave, slots)
-            self._wall_s += time.perf_counter() - t0
-            return out
+            return self._step_planned(wave, slots)
         self.wave_idx += 1
         wm = self._watermark()
         if self.faults is not None:
             self.faults.at_dispatch(self)
+        t_dispatch = time.perf_counter()
         self.store, out, self.clock = self._step_wave(wave, wm)
         if self.faults is not None:
             self.faults.at_retire(self)
@@ -305,14 +320,13 @@ class TxnService:
                                  np.asarray(wave.op_kind).shape[0])[None])
             if self.faults is not None:
                 self.faults.post_log(self)
-        self._route(out, slots)
+        self._route(out, slots, t_dispatch)
         self._observe_placement(wave, out, slots)
         if self.planner is not None:
             self.planner.observe_optimistic(
                 len(slots), int((out.status[:len(slots)] == ABORTED).sum()))
         if self.durability is not None:
             self.durability.maybe_snapshot(self, pipeline_empty=True)
-        self._wall_s += time.perf_counter() - t0
         return out
 
     def _step_planned(self, wave, slots):
@@ -327,6 +341,7 @@ class TxnService:
         wm = self._watermark()
         if self.faults is not None:
             self.faults.at_dispatch(self)
+        t_dispatch = time.perf_counter()
         self.store, self.clock, pw = run_wave_planned(
             self.store, wave, self.clock, wave_idx0=wave_idx0,
             next_tid=self.former.next_tid, sched=self.sched,
@@ -371,7 +386,7 @@ class TxnService:
             for r in (req, *req.folded):
                 r.tid = int(pw.exec_tid[i])
                 r.tids[-1] = r.tid
-        self._route(out, slots)
+        self._route(out, slots, t_dispatch)
         self._observe_placement(wave, out, slots)
         self.planner.observe_planned(
             len(slots), pw.plan.conflicted + pw.plan.n_spilled)
@@ -379,17 +394,20 @@ class TxnService:
             self.durability.maybe_snapshot(self, pipeline_empty=True)
         return out
 
-    def _route(self, out, slots):
+    def _route(self, out, slots, t_dispatch: float):
         """Route one synced wave's per-txn outcomes: commits record latency,
         aborts re-enter the retry calendar or drop.  Shared by the per-wave
         step loop and the streaming driver's block retirement (which calls
-        it once per wave of a retired block).
+        it once per wave of a retired block).  Every committed request is
+        stamped with ``t_dispatch``, when the wave's block went to the
+        device, and ``t_ack``, now.
 
         A folded row (DESIGN.md §12.2) fans its outcome out to every member
         request exactly once: on commit all members commit with the row's
         (s, c) — the summed delta IS their serial net effect — and on abort
         each member re-enters the retry calendar individually (it may fold
         into a different group next wave)."""
+        t_ack = time.perf_counter()
         for i, req in enumerate(slots):
             group = (req, *req.folded)
             req.folded = []
@@ -397,6 +415,7 @@ class TxnService:
             if out.status[i] == COMMITTED:
                 for r in group:
                     r.status = "committed"
+                    r.t_dispatch, r.t_ack = t_dispatch, t_ack
                     r.commit_tick = self.tick
                     r.s, r.c = int(out.s[i]), int(out.c[i])
                     self.committed += 1
@@ -620,7 +639,8 @@ class TxnService:
 
     # ------------------------------------------------------------ output
     def report(self) -> ServiceReport:
-        wall = max(self._wall_s, 1e-9)
+        wall = max(sum(self.stage_s.get(k, 0.0)
+                       for k in ("tick", "flush", "step")), 1e-9)
         admitted = self.former.admitted
         return ServiceReport(
             sched=self.sched,
@@ -659,6 +679,8 @@ class TxnService:
             tenants=self._tenant_report(),
             fold_groups=self.former.fold_groups,
             folded_requests=self.former.folded_requests,
+            stage_s=dict(self.stage_s),
+            stage_n=dict(self.stage_n),
         )
 
     def _tenant_report(self) -> Dict[str, Dict]:

@@ -61,6 +61,7 @@ import numpy as np
 from repro.core import ABORTED, Wave, WaveOut
 
 from .former import fold_counts
+from .obs import stage
 
 
 def _stack_np(waves: List[Wave]) -> Wave:
@@ -170,6 +171,7 @@ class _Block:
     stacked: Wave                               # numpy [B,T,O] block input
     wave_idx0: int                              # wave-index origin at dispatch
     wm: object = None                           # GC watermark at dispatch
+    t_dispatch: float = 0.0                     # perf_counter at dispatch
 
 
 class StreamingDriver:
@@ -211,21 +213,26 @@ class StreamingDriver:
             svc.step()
             return
         svc.tick += 1
-        t0 = time.perf_counter()
+        with stage(svc, "tick"):
+            self._tick()
+
+    def _tick(self) -> None:
+        svc = self.svc
         if self._buf_T is None:            # block boundary: propose sizes
             self._buf_T = self.sizer.T if self.sizer else svc.T
             self._buf_B = (self.sizer.B if self.sizer and self.sizer.adapt_B
                            else self.B)    # sizer owns B only when adapting
-        formed_n = 0
-        while len(self._buf) < self._buf_B:
-            if formed_n and svc.former.backlog(svc.tick) < self._buf_T:
-                break              # catch-up waves beyond the first must be
+        with stage(svc, "form"):
+            formed_n = 0
+            while len(self._buf) < self._buf_B:
+                if formed_n and svc.former.backlog(svc.tick) < self._buf_T:
+                    break          # catch-up waves beyond the first must be
                                    # full-T: thin waves waste device slots
-            formed = svc.former.form(svc.tick, T=self._buf_T)
-            if formed is None:
-                break
-            self._buf.append(formed)
-            formed_n += 1
+                formed = svc.former.form(svc.tick, T=self._buf_T)
+                if formed is None:
+                    break
+                self._buf.append(formed)
+                formed_n += 1
         if len(self._buf) == self._buf_B:
             self._dispatch()               # full block: ship it
         elif self._buf:
@@ -240,16 +247,14 @@ class StreamingDriver:
             svc.idle_ticks += 1
             if self._inflight:             # nothing to form: drain the pipe
                 self._retire_one(allow_delay=True)
-        svc._wall_s += time.perf_counter() - t0
 
     def flush(self) -> None:
         """Ship the partial block and sync every in-flight block."""
-        t0 = time.perf_counter()
-        if self._buf:
-            self._dispatch(retire_to=0)
-        while self._inflight:
-            self._retire_one()
-        self.svc._wall_s += time.perf_counter() - t0
+        with stage(self.svc, "flush"):
+            if self._buf:
+                self._dispatch(retire_to=0)
+            while self._inflight:
+                self._retire_one()
 
     def drain(self, max_ticks: Optional[int] = None) -> int:
         """Tick until no request is pending anywhere (former, open block,
@@ -279,14 +284,16 @@ class StreamingDriver:
         while self._buf:
             b = 1 << (len(self._buf).bit_length() - 1)   # max pow2 <= len
             chunk, self._buf = self._buf[:b], self._buf[b:]
-            meta = [(np.asarray(w.tid), slots) for w, slots in chunk]
-            stacked = _stack_np([w for w, _ in chunk])
-            outs, clock = svc._run_block(stacked)
+            with stage(svc, "dispatch"):
+                meta = [(np.asarray(w.tid), slots) for w, slots in chunk]
+                stacked = _stack_np([w for w, _ in chunk])
+                t_dispatch = time.perf_counter()
+                outs, clock = svc._run_block(stacked)
             wave_idx0, wm = svc._last_dispatch
             if svc.faults is not None:
                 svc.faults.at_dispatch(svc)   # kill: launched, not durable
-            self._inflight.append(
-                _Block(outs, clock, meta, stacked, wave_idx0, wm))
+            self._inflight.append(_Block(outs, clock, meta, stacked,
+                                         wave_idx0, wm, t_dispatch))
             svc.blocks += 1
         self._buf_T = self._buf_B = None
         limit = (self.K - 1) if retire_to is None else retire_to
@@ -308,8 +315,15 @@ class StreamingDriver:
         if svc.faults is not None:
             svc.faults.at_retire(svc)    # kill: computed, never logged/acked
         blk = self._inflight.popleft()
-        outs = jax.tree_util.tree_map(np.asarray, blk.outs)   # device sync
-        clock = int(blk.clock)
+        with stage(svc, "retire_wait"):
+            outs = jax.tree_util.tree_map(np.asarray, blk.outs)  # device sync
+            clock = int(blk.clock)
+        with stage(svc, "route"):
+            self._route_block(blk, outs, clock)
+
+    def _route_block(self, blk: _Block, outs: WaveOut, clock: int) -> None:
+        """GC, history, WAL and outcome routing of one synced block."""
+        svc = self.svc
         per_wave = []
         for j, (tids, slots) in enumerate(blk.waves):
             out_j = WaveOut(*(leaf[j] for leaf in outs))
@@ -329,7 +343,7 @@ class StreamingDriver:
             if svc.faults is not None:
                 svc.faults.post_log(svc)   # kill: durable-but-unacked window
         for out_j, slots in per_wave:
-            svc._route(out_j, slots)
+            svc._route(out_j, slots, blk.t_dispatch)
             n_abort = int((out_j.status[:len(slots)] == ABORTED).sum())
             if self.sizer is not None:
                 self.sizer.observe(len(slots), n_abort)
