@@ -16,6 +16,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from . import ref
 from .flash_attention import flash_attention_pallas
@@ -98,8 +99,8 @@ def version_scan(cids, tids, max_cid, *, use_pallas=False, interpret=False,
 
 
 # ---------------------------------------------------------------------------
-# batched commit-phase data movement (jnp scatter/gather: no Pallas variant —
-# XLA already emits single fused scatters; they live here so the substrate's
+# batched commit-phase data movement (jnp scatter/gather and single-element
+# in-place updates: no Pallas variant; they live here so the substrate's
 # whole data plane is kernel-plane ops and the engine body stays pure rule
 # arithmetic over op outputs)
 # ---------------------------------------------------------------------------
@@ -117,20 +118,48 @@ def masked_install(val, tid, cid, sid, head, wave, *, mask, keys, values,
 
     Pushes a new ring version for every key with ``mask`` set: the slot after
     ``head`` is overwritten, SID resets to 0, ``head``/``wave`` advance.
-    Masked-off rows are routed to an OOB sentinel and dropped by the scatter;
-    masked/NOP keys (which may be negative padding) are clamped before the
-    ``head`` gather so they can never wrap to a real key.  Returns the six
-    updated ring arrays.
+    Keys are clamped before the ``head`` gather so masked/NOP keys (which may
+    be negative padding) can never wrap to a real key; a key with ``mask``
+    set must be in range.  Returns the six updated ring arrays.
+
+    The four ``[n_keys, V]`` rings are written by one scatter each, with
+    masked-off rows routed to an OOB sentinel and dropped.  ``head`` and
+    ``wave`` are written by one single-element in-place update per op
+    instead: on a TPU a scatter into a 1-D ``[n_keys]`` array stages the
+    whole array in scoped memory wherever it fits there (at 3M keys, not at
+    2^23), a full read and write of it per call, while a
+    ``dynamic_update_slice`` touches one element.  Each op writes its
+    clamped key's final tag: the new one where any op with ``mask`` set
+    hits that key, else the one it had.  So masked-off ops and NOP keys
+    change nothing, and duplicate keys end as the scatter leaves them
+    (every op on one key carries the same ``h_new`` and ``wave_idx``).
+    The final tags are computed before the writes, so the writes are
+    independent of one another and need no read of the array between them.
     """
     n_keys, n_versions = val.shape
+    k = jnp.clip(keys, 0, n_keys - 1)
+    h_cur = head[k]
+    h_new = (h_cur + 1) % n_versions
     k_install = jnp.where(mask, keys, n_keys)
-    h_new = (head[jnp.clip(keys, 0, n_keys - 1)] + 1) % n_versions
-    return (val.at[k_install, h_new].set(values, mode="drop"),
+    rows = (val.at[k_install, h_new].set(values, mode="drop"),
             tid.at[k_install, h_new].set(new_tid, mode="drop"),
             cid.at[k_install, h_new].set(new_cid, mode="drop"),
-            sid.at[k_install, h_new].set(0, mode="drop"),
-            head.at[k_install].set(h_new, mode="drop"),
-            wave.at[k_install].set(wave_idx, mode="drop"))
+            sid.at[k_install, h_new].set(0, mode="drop"))
+    k, m = k.ravel(), jnp.broadcast_to(mask, keys.shape).ravel()
+    hit = ((k[:, None] == k[None, :]) & m[None, :]).any(axis=1)
+    head_fin = jnp.where(hit, h_new.ravel(), h_cur.ravel())
+    wave_fin = jnp.where(hit, jnp.broadcast_to(wave_idx, keys.shape).ravel(),
+                         wave[k]).astype(wave.dtype)
+    for j in range(k.shape[0]):
+        head = _put(head, head_fin[j], k[j])
+        wave = _put(wave, wave_fin[j], k[j])
+    return rows + (head, wave)
+
+
+def _put(a, x, i):
+    """``a`` with element ``i`` (in range) set to ``x``, in place."""
+    return lax.dynamic_update_slice(a, x[None], (i,),
+                                    allow_negative_indices=False)
 
 
 def masked_sid_bump(sid, tid, *, mask, keys, slots, expect_tid, s_val):

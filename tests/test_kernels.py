@@ -288,3 +288,77 @@ def test_wave_commit_nop_padding_no_false_edges(pad_key):
             rk, wk, use_pallas=use_pallas,
             interpret=use_pallas)).astype(bool)
         np.testing.assert_array_equal(pot, pot_u)
+
+
+# ------------------------------------------------------------ masked install
+def _scatter_install(val, tid, cid, sid, head, wave, *, mask, keys, values,
+                     new_tid, new_cid, wave_idx):
+    """The install as six scatters, ``head`` and ``wave`` included: the
+    reference the in-place ``ops.masked_install`` must match bit for bit."""
+    n_keys, n_versions = val.shape
+    k_install = jnp.where(mask, keys, n_keys)
+    h_new = (head[jnp.clip(keys, 0, n_keys - 1)] + 1) % n_versions
+    return (val.at[k_install, h_new].set(values, mode="drop"),
+            tid.at[k_install, h_new].set(new_tid, mode="drop"),
+            cid.at[k_install, h_new].set(new_cid, mode="drop"),
+            sid.at[k_install, h_new].set(0, mode="drop"),
+            head.at[k_install].set(h_new, mode="drop"),
+            wave.at[k_install].set(wave_idx, mode="drop"))
+
+
+def _install_batch(rng, O, n_keys, V):
+    """One random transaction's install inputs over a small store: keys
+    drawn so that the ends 0 and n_keys-1, duplicates and negative NOP
+    padding (always masked off, as the engine's NOP ops are) are common."""
+    store = [rng.integers(-5, 50, (n_keys, V)) for _ in range(4)] + [
+        rng.integers(0, V, n_keys), rng.integers(-1, 9, n_keys)]
+    pool = np.array([0, n_keys - 1, 1, 2, -1, -3])
+    keys = np.where(rng.random(O) < 0.6, rng.choice(pool, O),
+                    rng.integers(0, n_keys, O))
+    mask = (rng.random(O) < 0.6) & (keys >= 0)
+    args = dict(mask=mask, keys=keys, values=rng.integers(-9, 99, O),
+                new_tid=rng.integers(0, 999), new_cid=rng.integers(0, 999),
+                wave_idx=rng.integers(0, 99))
+    return ([jnp.asarray(a, jnp.int32) for a in store],
+            {n: jnp.asarray(a, jnp.bool_ if n == "mask" else jnp.int32)
+             for n, a in args.items()})
+
+
+_INSTALL_NAMES = ("val", "tid", "cid", "sid", "head", "wave")
+
+
+def _assert_installs_equal(store, args, tag):
+    got = jax.jit(ops.masked_install)(*store, **args)
+    want = jax.jit(_scatter_install)(*store, **args)
+    for name, a, b in zip(_INSTALL_NAMES, got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=f"{tag}.{name}")
+
+
+@pytest.mark.parametrize("seed", range(4))
+@pytest.mark.parametrize("O", [1, 4, 8])
+def test_masked_install_matches_scatter(O, seed):
+    """``head``/``wave`` written in place, op by op, end as the 1-D scatters
+    left them: random batches with masked-off ops, NOP keys, the key range's
+    ends and duplicate keys."""
+    rng = np.random.default_rng(1000 * O + seed)
+    for b in range(25):
+        _assert_installs_equal(*_install_batch(rng, O, n_keys=6, V=4),
+                               f"O={O}.batch{b}")
+
+
+@pytest.mark.parametrize("masks", [(True, True), (True, False),
+                                   (False, True), (False, False)])
+@pytest.mark.parametrize("key", [0, 3, 5])
+def test_masked_install_duplicate_keys(key, masks):
+    """Two ops of one transaction on one key, under every pair of masks,
+    beside a masked-off NOP key: the key ends as the scatter left it."""
+    n_keys, V = 6, 4
+    rng = np.random.default_rng(key)
+    store, _ = _install_batch(rng, 4, n_keys, V)
+    args = dict(mask=jnp.array(masks + (False, True)),
+                keys=jnp.array([key, key, -1, (key + 1) % n_keys], jnp.int32),
+                values=jnp.array([7, 8, 9, 10], jnp.int32),
+                new_tid=jnp.int32(41), new_cid=jnp.int32(42),
+                wave_idx=jnp.int32(43))
+    _assert_installs_equal(store, args, f"key={key}.masks={masks}")

@@ -11,6 +11,9 @@ worker imports this file, so the topology is described inside a fixture
 and never at import.  The persistent compilation cache is off around these
 compiles: a TPU entry written here could not be read back without a chip.
 """
+import json
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -26,6 +29,7 @@ from repro.kernels import KernelConfig, ops
 
 T, V, B, O_SERVED = 256, 8, 4, 4
 N_KEYS = 2 ** 23
+N_SERVED = 3_000_000        # the chip benchmark's SmallBank: 3 tables x 1M
 HBM_BYTES = 16e9            # one v5e chip (Google Cloud documentation)
 
 
@@ -86,8 +90,8 @@ def test_kernel_compiles_for_v5e(one_chip, op, O):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def _store(sh):
-    return MVStore(*([_i32((N_KEYS, V), sh)] * 4 + [_i32((N_KEYS,), sh)] * 2))
+def _store(sh, n_keys=N_KEYS):
+    return MVStore(*([_i32((n_keys, V), sh)] * 4 + [_i32((n_keys,), sh)] * 2))
 
 
 def _block(sh):
@@ -111,6 +115,37 @@ def test_scan_block_compiles_for_v5e(one_chip, kernels):
         kernels=KernelConfig(kernels)).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert _fits_hbm(compiled)
+
+
+def _scoped_bytes(compiled, scope):
+    """(largest scoped-memory size in bytes, op name) of each compiled op
+    whose ``op_name`` lies under the named ``scope``."""
+    found = []
+    for line in compiled.as_text().splitlines():
+        name = re.search(r'op_name="([^"]*)"', line)
+        used = re.search(r'"used_scoped_memory_configs":(\[[^]]*\])', line)
+        if name and used and f"/{scope}/" in name.group(1):
+            sizes = [int(c["size"]) for c in json.loads(used.group(1))]
+            found.append((max(sizes, default=0), line.split("=")[0].strip()))
+    return found
+
+
+@pytest.mark.parametrize("kernels", ["pallas", "pallas+fused"])
+def test_commit_loop_stages_no_whole_key_array(one_chip, kernels):
+    """The block program at the benchmark's store size: no op of the commit
+    loop, which runs once per transaction, stages a whole per-key array
+    (``store.head`` / ``store.wave``, 4 bytes a key) in scoped memory.
+    A 1-D scatter into such an array does at this size; the install's
+    single-element in-place updates do not."""
+    scalar = _i32((), one_chip)
+    compiled = engine._scan_block.lower(
+        _store(one_chip, N_SERVED), _block(one_chip), scalar, scalar, scalar,
+        None, None, sched="postsi", gc_track=True,
+        kernels=KernelConfig(kernels)).compile()
+    scoped = _scoped_bytes(compiled, "commit_loop")
+    assert scoped, "no commit-loop op found in the compiled text"
+    staged = [op for size, op in scoped if size >= 4 * N_SERVED]
+    assert not staged, f"whole-array staging in the commit loop: {staged}"
 
 
 @pytest.mark.parametrize("kernels", ["pallas", "pallas+fused"])
