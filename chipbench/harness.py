@@ -1,13 +1,20 @@
 """One run of one cell: set-up, the measured window, the follow-up of the
 window's requests, and the check that decides ``correct``.
 
-Everything that belongs to one configuration, traffic mix or per-layer
-metric lives in a file of its own, found by the name ``BENCHMARK.json``
-gives it:
+Everything that belongs to one configuration, traffic mix, transaction
+mix or per-layer metric lives in a file of its own, found by its name
+(``chipbench/files.py``):
 
-    chipbench/configs/<config>.json    deployment: sizes, mix, guarantee
+    chipbench/configs/<config>.json    deployment: sizes, mix, service
     chipbench/traffic/<traffic>.json   arrivals: open rate or closed clients
+    chipbench/mixes/<kind>.py          transactions: ``draw`` and ``check``
     chipbench/metrics/<metric>.py      reader: ``read(ctx) -> float | None``
+
+A configuration's optional ``service`` object is passed to ``TxnService``
+as keyword arguments.  The harness hands the program each generated row as
+it is and reads back every per-row output and every per-key store field,
+whatever their trailing shapes, so a mix whose records are wider than one
+int32 needs no edit here.
 
 The program serves on logical ticks (``TxnService``, ``StreamingDriver``);
 this harness supplies the wall clock.  It calls ``TxnService.submit`` and
@@ -20,7 +27,6 @@ from __future__ import annotations
 
 import contextlib
 import gc as _gc
-import importlib.util
 import json
 import os
 import time
@@ -29,10 +35,10 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from chipbench import gen, reference
+from chipbench import trace as tracemod
+from chipbench.files import ROOT, BenchError, load_json, load_module
 from chipbench.load import ClientPool, OpenLoop
 
-HERE = os.path.dirname(os.path.abspath(__file__))
-ROOT = os.path.dirname(HERE)
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 DONE = ("committed", "dropped", "rejected")
 FOLLOW_S = 60.0           # how long past the window's close a request due
@@ -41,42 +47,23 @@ STREAM_LEN = 1 << 20      # closed-loop stream; wraps past its end
 BLOCK_SHAPES = (4, 2, 1)  # power-of-two block sizes the driver dispatches
 
 
-class BenchError(Exception):
-    """The run cannot measure what the cell asks for."""
-
-
 # ----------------------------------------------------------------- lookup
 def load_spec(root: str = ROOT) -> dict:
     with open(os.path.join(root, "BENCHMARK.json")) as f:
         return json.load(f)
 
 
-def _json(kind: str, name: str, root: str) -> dict:
-    path = os.path.join(root, "chipbench", kind, name + ".json")
-    if not os.path.exists(path):
-        raise BenchError(f"no {kind} file {path}")
-    with open(path) as f:
-        return json.load(f)
-
-
 def load_config(name: str, root: str = ROOT) -> dict:
-    return _json("configs", name, root)
+    return load_json("configs", name, root)
 
 
 def load_traffic(name: str, root: str = ROOT) -> dict:
-    return _json("traffic", name, root)
+    return load_json("traffic", name, root)
 
 
 def metric_reader(name: str, root: str = ROOT):
     """The ``read`` function of ``chipbench/metrics/<name>.py``."""
-    path = os.path.join(root, "chipbench", "metrics", name + ".py")
-    if not os.path.exists(path):
-        raise BenchError(f"no reader for metric {name!r} at {path}")
-    spec = importlib.util.spec_from_file_location(
-        "chipbench_metric_" + name.replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return load_module("metrics", name, root).read
 
 
 def cell_metrics(spec: dict, cell: str, kind: str) -> List[dict]:
@@ -138,32 +125,42 @@ class Served:
             n_keys=cfg["n_keys"], n_versions=cfg["n_versions"], T=cfg["T"],
             O=cfg["O"], sched=cfg["scheduler"],
             n_nodes=cfg["n_nodes"], seed=seed % 2 ** 32, mesh=mesh,
-            kernels=kernels)
+            kernels=kernels, **cfg.get("service", {}))
         self.drv = StreamingDriver(self.svc, B=cfg["B"], K=cfg["K"])
         self.svc.stream = self.drv
         jax.block_until_ready(self.svc.store)
         self.tick_end: List[float] = [time.perf_counter()]
 
-    def warm_shapes(self) -> None:
-        """Run every block shape the driver can dispatch, on NOP waves and
-        with the argument types the service passes, and drop the results:
-        the service's own state is left as it was.  On a mesh each shape
-        runs twice, first on the service's initial clock and then on the
-        clock a block returns, because there the two carry different
-        shardings and so are different programs."""
-        import jax
+    def nop_block(self, B: int, val_row: tuple):
+        """A block of ``B`` NOP waves in the shapes the service dispatches:
+        its own (``TxnService.nop_block``) where it offers one, else waves
+        stacked from rows shaped as the harness submits them, ``val_row``
+        being one row's ``Txns.val`` shape."""
+        svc = self.svc
+        if hasattr(svc, "nop_block"):
+            return svc.nop_block(B)
         from repro.core import Wave
+        T = svc.T
+        zeros = lambda *shape: np.zeros((B, T) + shape, np.int32)
+        return Wave(op_kind=zeros(svc.O), op_key=zeros(svc.O),
+                    op_val=zeros(*val_row), host=zeros(),
+                    tid=2 ** 30 + np.arange(B * T, dtype=np.int32)
+                    .reshape(B, T))
+
+    def warm_shapes(self, val_row: tuple) -> None:
+        """Run every block shape the driver can dispatch, on NOP waves and
+        with the argument types the service passes (``nop_block``), and
+        drop the results: the service's own state is left as it was.  On
+        a mesh each shape runs twice, first on the service's initial clock
+        and then on the clock a block returns, because there the two carry
+        different shardings and so are different programs."""
+        import jax
         svc, cfg = self.svc, self.cfg
-        T, O = cfg["T"], cfg["O"]
         wave_idx, clock, store = svc.wave_idx, svc.clock, svc.store
         for B in BLOCK_SHAPES:
             if B > cfg["B"]:
                 continue
-            tid = 2 ** 30 + np.arange(B * T, dtype=np.int32).reshape(B, T)
-            nop = Wave(op_kind=np.zeros((B, T, O), np.int32),
-                       op_key=np.zeros((B, T, O), np.int32),
-                       op_val=np.zeros((B, T, O), np.int32),
-                       host=np.zeros((B, T), np.int32), tid=tid)
+            nop = self.nop_block(B, val_row)
             for _ in range(2 if cfg["chips"] > 1 else 1):
                 outs, _ = svc._run_block(nop)
                 jax.block_until_ready(outs)
@@ -309,15 +306,47 @@ def counters(svc) -> Dict[str, int]:
             "waves": svc.wave_idx, "blocks": svc.blocks, "tick": svc.tick}
 
 
+def stages(svc) -> tuple:
+    """The service's stage timers now: seconds and count per stage."""
+    return dict(svc.stage_s), dict(svc.stage_n)
+
+
+def latency_parts(reqs, due, sent, tick_end) -> Dict[str, np.ndarray]:
+    """Commit latency of each committed request of ``reqs``, and its five
+    parts (``PARTS``) on the harness's clock, from its due and send time
+    and the request's own stamps (``TxnRequest.t_submit``,
+    ``t_dispatch``, ``t_ack``): they add up to it."""
+    ok = [i for i, r in enumerate(reqs) if r.status == "committed"]
+    col = lambda f: np.fromiter((f(i) for i in ok), np.float64, len(ok))
+    ack = col(lambda i: tick_end[reqs[i].commit_tick])
+    t_sub = col(lambda i: reqs[i].t_submit)
+    t_dis = col(lambda i: reqs[i].t_dispatch)
+    t_ack = col(lambda i: reqs[i].t_ack)
+    d, s = col(lambda i: due[i]), col(lambda i: sent[i])
+    return {"latency": ack - d, "gen_lag": s - d, "admit": t_sub - s,
+            "queue_wait": t_dis - t_sub, "block_turn": t_ack - t_dis,
+            "tick_rest": ack - t_ack}
+
+
+PARTS = ("gen_lag", "admit", "queue_wait", "block_turn", "tick_rest")
+
+
 # -------------------------------------------------------------------- checks
 def history_of(svc) -> reference.History:
+    """The served history, wave after wave: every output with one row per
+    transaction, the named ones as ``History``'s fields and any other in
+    ``History.extra``."""
     h = svc.history
-    cat = lambda f: np.concatenate([np.asarray(getattr(o, f)) for _, o in h])
+    rows = {}
+    for f in h[0][1]._fields:
+        parts = [np.asarray(getattr(o, f)) for _, o in h]
+        if all(p.ndim and len(p) == len(t) for p, (t, _) in zip(parts, h)):
+            rows[f] = np.concatenate(parts)
+    named = {f: rows.pop(f) for f in reference.History._fields
+             if f not in ("tid", "extra")}
     return reference.History(
-        tid=np.concatenate([np.asarray(t) for t, _ in h]),
-        status=cat("status"), s=cat("s"), c=cat("c"),
-        read_key=cat("read_key"), read_cid=cat("read_cid"),
-        write_key=cat("write_key"), write_cid=cat("write_cid"))
+        tid=np.concatenate([np.asarray(t) for t, _ in h]), **named,
+        extra=rows)
 
 
 def answers_of(reqs) -> reference.Answers:
@@ -333,14 +362,20 @@ def answers_of(reqs) -> reference.Answers:
 
 def store_of(store, n_keys: int) -> tuple:
     """Host copies of the final store, read from each field's owning
-    shards (one shard on one chip); also the devices that hold them."""
+    shards (one shard on one chip); also the devices that hold them.
+    Every field with one row per key (as many as ``val``, a mesh's padding
+    rows cut off) is read: the named ones as ``Store``'s fields and any
+    other in ``Store.extra``."""
     def read(a):
         shards = sorted(a.addressable_shards,
                         key=lambda s: s.index[0].start or 0)
         return np.concatenate([np.asarray(s.data) for s in shards])[:n_keys]
+    rows = {f: read(a) for f, a in store._asdict().items()
+            if a.ndim and a.shape[0] == store.val.shape[0]}
+    named = {f: rows.pop(f) for f in reference.Store._fields
+             if f != "extra"}
     devices = sorted({str(s.device) for s in store.val.addressable_shards})
-    return reference.Store(read(store.val), read(store.tid),
-                           read(store.cid), read(store.head)), devices
+    return reference.Store(**named, extra=rows), devices
 
 
 # ---------------------------------------------------------------------- run
@@ -357,7 +392,14 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
              traffic: Optional[dict] = None,
              root: str = ROOT) -> dict:
     """Run one cell; returns ``{"line": result line, "info": what the
-    run saw besides}`` (compiles, window counters, check time).
+    run saw besides}`` (compiles, window counters, check time) and the
+    ``ctx`` its per-layer readers read.  Beside the harness's own
+    readings, ``ctx`` carries what the program records of the window: the
+    deltas of its stage timers (``stage_s``, ``stage_n``, at the marks of
+    ``window``), each committed request's latency parts from its own
+    stamps (``latency_parts``, and ``queue_wait_s``, ``block_turn_s``),
+    and in a traced run its device time by named scope (``scoped``,
+    ``trace.Scoped``; None untraced).
 
     ``kernels`` and ``traffic`` override the kernel backend and the cell's
     traffic mix (for the CPU tests and the knee sweep); ``root`` is the
@@ -365,29 +407,30 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
     import jax
     cfg = dict(load_config(cell["config"], root), chips=cell["chips"])
     traffic = traffic or load_traffic(cell["traffic"], root)
+    mix = gen.load_mix(cfg["mix"]["kind"], root)
     if traffic["loop"] == "open":
         segments = (traffic["warmup_s"], seconds, FOLLOW_S)
         due = gen.arrivals(seed, traffic["rate_txn_s"], segments)
-        txns = gen.draw(mix_of(cfg), seed, len(due))
+        txns = gen.draw(mix_of(cfg), seed, len(due), root)
     else:
         due = None
-        txns = gen.draw(mix_of(cfg), seed, STREAM_LEN)
+        txns = gen.draw(mix_of(cfg), seed, STREAM_LEN, root)
 
     with CompileClock() as clk, collector_off():
         served = Served(cfg, seed, kernels=kernels)
-        served.warm_shapes()
+        served.warm_shapes(txns.val.shape[1:])
         load = Load(served, traffic, txns, due)
         tw0 = load.t0 + traffic["warmup_s"]
         load.pump(tw0)                            # load settles
         load.ramping = False
         compiles_setup = clk.n
-        c0 = counters(served.svc)
+        c0, st0 = counters(served.svc), stages(served.svc)
         span0 = len(load.spans)
         tw0 = time.perf_counter()
         setup_s = tw0 - t_start
         tw1 = tw0 + seconds
         load.pump(tw1)
-        c1 = counters(served.svc)
+        c1, st1 = counters(served.svc), stages(served.svc)
         span1 = len(load.spans)
         compiles_window = clk.n - compiles_setup
         unsent = held_back = 0
@@ -402,7 +445,6 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
         win_reqs = [load.reqs[i] for i in in_win]
         tr = None
         if trace:           # a few seconds more, traced, after the window
-            from chipbench import trace as tracemod
             tr = tracemod.Tracer(os.path.join(root, ".chipbench_trace"))
             served.drv.flush()
             w_tr0 = served.svc.wave_idx
@@ -439,18 +481,29 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
     spans = load.spans[span0:span1]
     failed = int(sum(r.status != "committed" for r in win_reqs)) + unsent
     unanswered = int(sum(r.status not in DONE for r in win_reqs)) + unsent
+    parts = latency_parts(win_reqs, due_arr[in_win],
+                          np.asarray(load.sent)[in_win], ticks)
     ctx = Context(cell=cell, cfg=cfg, traffic=traffic, seconds=seconds,
                   window={k: c1[k] - c0[k] for k in c0},
-                  latency_s=lat, gen_lag_s=lag,
+                  stage_s={k: v - st0[0].get(k, 0.0)
+                           for k, v in st1[0].items()},
+                  stage_n={k: v - st0[1].get(k, 0)
+                           for k, v in st1[1].items()},
+                  latency_s=lat, gen_lag_s=lag, latency_parts=parts,
+                  queue_wait_s=parts["queue_wait"],
+                  block_turn_s=parts["block_turn"],
                   submit_s=sum(s[1] for s in spans),
                   submitted=sum(s[2] for s in spans),
-                  goodput_txn_s=goodput, trace=None,
+                  goodput_txn_s=goodput, trace=None, scoped=None,
                   device_kind=devices[0].device_kind)
     trace_info = {}
     if tr is not None:
         ctx.trace = tr.reduce(n_devices=cfg["chips"], waves=w_tr)
+        t_scoped = time.perf_counter()
+        ctx.scoped = tracemod.reduce_scoped(tr.raw, w_tr)
         trace_info = {"trace_waves": w_tr, "trace_bytes": tr.file_bytes,
-                      "trace_reduce_s": tr.reduce_s}
+                      "trace_reduce_s": tr.reduce_s,
+                      "scoped_reduce_s": time.perf_counter() - t_scoped}
 
     # ---- the check that decides ``correct`` --------------------------------
     t_ref = time.perf_counter()
@@ -462,7 +515,7 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
     n_keys = cfg["n_keys"]
     del served, load, svc
     _gc.collect()
-    checks = reference.check(sub, ans, hist, store)
+    checks = mix.check(sub, ans, hist, store)
     checks["never_answered"] = unanswered
     if cfg["chips"] > 1:
         checks["shard_devices"] = abs(len(store_devs) - cfg["chips"])
@@ -490,7 +543,8 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
     if ctx.trace is not None:
         device["busy_s"] = ctx.trace.busy_s
         device["window_s"] = ctx.trace.window_s
-        line["breakdown"] = ctx.trace.breakdown()
+        b = ctx.scoped.breakdown()
+        line["breakdown"] = {k: b[k] for k in ("device_ops", "idle_gaps")}
     line["checks"] = {k: {"value": v, "limit": 0} for k, v in checks.items()}
     info = {"compiles_setup": compiles_setup,
             "compiles_window": compiles_window,
@@ -499,7 +553,7 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
             "check_s": check_s, "window": ctx.window,
             "requests": len(ans.committed), "history_rows": len(hist.tid),
             "n_keys": n_keys, **trace_info}
-    return {"line": line, "info": info}
+    return {"line": line, "info": info, "ctx": ctx}
 
 
 class Unanswered:

@@ -1,7 +1,7 @@
 """Commit loop: device ms per traced wave of the operations under the
 block program's ``commit_loop`` scope (``engine.run_wave_on``), leaf
 operations only, from each operation's ``tf_op`` in the trace.  Reads
-``ctx.scoped`` (``chipbench.observe.Scoped``); None where the context
+``ctx.scoped`` (``chipbench.trace.Scoped``); None where the context
 does not carry it or no operation carries the scope."""
 
 

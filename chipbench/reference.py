@@ -6,7 +6,9 @@ itself generated and submitted (``Txns``), the program's answers to those
 requests (status and the TIDs each execution ran under), the served
 history (per-row status, start/commit times, read and write keys with the
 CIDs read and stamped) and the final store, read back shard by shard.
-Checked, each as a count whose limit is 0:
+Every other per-row output of the program and every other per-key field
+of its store arrive in ``History.extra`` and ``Store.extra``, for a mix's
+own check (``chipbench/mixes/``).  ``check`` counts, each with limit 0:
 
 * ``exactly_once`` — every acknowledged request owns exactly one committed
   history row (the one of its last execution) and every other request
@@ -45,6 +47,7 @@ class History(NamedTuple):
     read_cid: np.ndarray   # [N, O]
     write_key: np.ndarray  # [N, O] (-1 where not a write)
     write_cid: np.ndarray  # [N, O]
+    extra: Dict[str, np.ndarray] = {}   # every other [N, ...] output
 
 
 class Answers(NamedTuple):
@@ -62,6 +65,7 @@ class Store(NamedTuple):
     tid: np.ndarray
     cid: np.ndarray
     head: np.ndarray
+    extra: Dict[str, np.ndarray] = {}   # every other [n_keys, ...] field
 
 
 def _code(key, cid) -> np.ndarray:

@@ -1,8 +1,11 @@
-"""The copied generators, the load loops and the peak table."""
+"""The mixes, the arrival processes, the load loops and the peak table."""
 import numpy as np
 import pytest
 
 from chipbench import gen, load, peaks
+from chipbench.files import BenchError
+
+ycsb, smallbank = gen.load_mix("ycsb"), gen.load_mix("smallbank")
 
 YCSB = dict(n_nodes=8, keys_per_node=1 << 20, theta=0.99, read_frac=0.5,
             dist_frac=0.1, n_ops=4)
@@ -12,8 +15,8 @@ ACCOUNTS = 1_000_000
 
 
 @pytest.mark.parametrize("draw", [
-    lambda s: gen.ycsb(s, 5000, **YCSB),
-    lambda s: gen.smallbank(s, 5000, **SB),
+    lambda s: ycsb.draw(s, 5000, **YCSB),
+    lambda s: smallbank.draw(s, 5000, **SB),
     lambda s: (gen.poisson_times(s, 3000.0, 2.0),),
 ], ids=["ycsb", "smallbank", "poisson"])
 def test_seed_reproduces_its_stream(draw):
@@ -30,7 +33,7 @@ def active_nodes(x, n_nodes):
 
 
 def test_ycsb_read_rmw_split_and_distributed_share():
-    x = gen.ycsb(3, 40000, **YCSB)
+    x = ycsb.draw(3, 40000, **YCSB)
     act = x.kind != gen.NOP
     reads = (x.kind == gen.READ).sum() / act.sum()
     assert abs(reads - 0.5) < 0.01
@@ -49,17 +52,17 @@ def test_ycsb_read_rmw_split_and_distributed_share():
 
 def test_ycsb_rank0_zipf_mass():
     n = 1 << 20
-    x = gen.ycsb(5, 200000, **dict(YCSB, n_ops=1))
+    x = ycsb.draw(5, 200000, **dict(YCSB, n_ops=1))
     share = np.mean(x.key[:, 0] // 8 == 0)
-    want = gen.zipf_cdf(n, 0.99)[0]
+    want = ycsb.zipf_cdf(n, 0.99)[0]
     sigma = np.sqrt(want * (1 - want) / len(x))
     assert abs(share - want) < 4 * sigma
-    assert gen.zipf_cdf(1000, 0.0)[0] == pytest.approx(1e-3)
+    assert ycsb.zipf_cdf(1000, 0.0)[0] == pytest.approx(1e-3)
 
 
 @pytest.mark.parametrize("draw", [
-    lambda: gen.ycsb(9, 20000, **YCSB),
-    lambda: gen.smallbank(9, 20000, **dict(SB, keys_per_node=3)),
+    lambda: ycsb.draw(9, 20000, **YCSB),
+    lambda: smallbank.draw(9, 20000, **dict(SB, keys_per_node=3)),
 ], ids=["ycsb", "smallbank"])
 def test_nop_dedup(draw):
     x = draw()
@@ -72,17 +75,17 @@ def test_nop_dedup(draw):
 
 
 def test_smallbank_procedures():
-    x = gen.smallbank(11, 85000, **SB)
+    x = smallbank.draw(11, 85000, **SB)
     table, cust = x.key // ACCOUNTS, x.key % ACCOUNTS
     act = x.kind != gen.NOP
     reads = (x.kind == gen.READ).sum(axis=1)
     rmws = (x.kind == gen.RMW).sum(axis=1)
     # every procedure looks its customer up in the account table first
     assert (x.kind[:, 0] == gen.READ).all()
-    assert (table[:, 0] == gen.ACCOUNT).all()
+    assert (table[:, 0] == smallbank.ACCOUNT).all()
     balance = (reads == 3) & (rmws == 0)
-    deposit = (reads == 1) & (rmws == 1) & (table[:, 1] == gen.CHECKING)
-    savings = (reads == 1) & (rmws == 1) & (table[:, 1] == gen.SAVINGS)
+    deposit = (reads == 1) & (rmws == 1) & (table[:, 1] == smallbank.CHECKING)
+    savings = (reads == 1) & (rmws == 1) & (table[:, 1] == smallbank.SAVINGS)
     payment = (reads == 2) & (rmws == 2)
     check = (reads == 2) & (rmws == 1)
     # amalgamate is left out; the other five keep their weights, over 85
@@ -102,11 +105,11 @@ def test_smallbank_procedures():
     assert ((x.key % 8)[one] == np.broadcast_to(x.host[:, None],
                                                 x.key.shape)[one]).all()
     with pytest.raises(ValueError):
-        gen.smallbank(1, 10, **dict(SB, weights=[1, 2]))
+        smallbank.draw(1, 10, **dict(SB, weights=[1, 2]))
     with pytest.raises(ValueError, match="amalgamate"):
-        gen.smallbank(1, 10, **dict(SB, weights=[15, 15, 15, 25, 15, 15]))
+        smallbank.draw(1, 10, **dict(SB, weights=[15, 15, 15, 25, 15, 15]))
     with pytest.raises(ValueError):
-        gen.smallbank(1, 10, **dict(SB, keys_per_node=1000))
+        smallbank.draw(1, 10, **dict(SB, keys_per_node=1000))
 
 
 def test_every_seed_draws_its_own_stream():
@@ -121,7 +124,7 @@ def test_every_seed_draws_its_own_stream():
     assert (np.diff(ta) >= 0).all() and ta[999] < 1.0 <= ta[1000] < 3.0
     assert tb[999] < 1.0 <= tb[1000] and not np.allclose(ta, tb)
     assert np.array_equal(ta, gen.arrivals(2 ** 32 + 3, 1000.0, (1.0, 2.0)))
-    with pytest.raises(ValueError):
+    with pytest.raises(BenchError, match="mixes/tpcc.py"):
         gen.draw(dict(mix, kind="tpcc"), 1, 5)
 
 
@@ -178,3 +181,37 @@ def test_peaks_are_keyed_by_device_kind():
     assert peaks.peaks("TPU v5 lite")["hbm_bytes_per_s"] == 819e9
     with pytest.raises(KeyError, match="no published peaks"):
         peaks.peaks("TPU v9 imaginary")
+
+
+# sha256 over ``kind``, ``key``, ``val`` and ``host`` of 4,096 transactions,
+# as ``gen.draw`` drew them while both mixes still lived in ``gen.py``
+DRAWN = {
+    ("smallbank_1m", 7):
+        "e36b35b2c6358131cf8024e81704d42a5f6570b65e73a47ed9a30b88805f96e0",
+    ("smallbank_1m", 2 ** 31 + 5):
+        "45028008f3baa5f048708b1465f1cd261c4a7f0e4b131dc4e5259e597577aaf8",
+    ("smallbank_1m", 2 ** 32 + 3):
+        "81e5d6e5bb568a028a288819f86b18b3e4d8b9ce736318ca9ed4497ec366b467",
+    ("tiny_ycsb", 7):
+        "215417d724df6176edece54e65b658e4429cb9ff6ca720086db161fdf73da7ea",
+    ("tiny_ycsb", 2 ** 31 + 5):
+        "0a21879185dedb77d530a8de33f4a67a67d7fa52ae80abba334a789af8c1fb61",
+    ("tiny_ycsb", 2 ** 32 + 3):
+        "b0878c52e54a769a6b270326b91201ddf25064a40068c3931f6e48be9eba8e9d",
+}
+
+
+@pytest.mark.parametrize("name, seed", sorted(DRAWN))
+def test_a_mix_file_draws_the_stream_gen_drew(name, seed):
+    import hashlib
+
+    from chipbench import harness
+    mix = harness.mix_of(harness.load_config(name)) if name != "tiny_ycsb" \
+        else dict(kind="ycsb", theta=0.99, read_frac=0.5, dist_frac=0.1,
+                  n_ops=4, n_nodes=8, keys_per_node=384)
+    x = gen.draw(mix, seed, 4096)
+    h = hashlib.sha256()
+    for a in (x.kind, x.key, x.val, x.host):
+        assert a.dtype == np.int32
+        h.update(a.tobytes())
+    assert h.hexdigest() == DRAWN[name, seed]

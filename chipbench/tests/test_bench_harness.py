@@ -11,7 +11,7 @@ import time
 
 import pytest
 
-from chipbench import faults, harness
+from chipbench import faults, gen, harness
 
 ROOT = harness.ROOT
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -53,6 +53,26 @@ def test_benchmark_json_names_files_that_exist():
         assert 0.01 <= m["bound"] <= 0.25
 
 
+# A mix of a test's own: SmallBank's draw and check, and one count more,
+# ``audit_forced``, which reads what ``BANK_AUDIT_FORCED`` says (0 unset).
+BANK_AUDIT = """
+import os
+
+from chipbench import gen
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+bank = gen.load_mix("smallbank", ROOT)
+draw = bank.draw
+
+
+def check(txns, answers, history, store):
+    out = bank.check(txns, answers, history, store)
+    out["audit_forced"] = int(os.environ.get("BANK_AUDIT_FORCED", "0"))
+    return out
+"""
+
+
 @pytest.fixture(scope="module")
 def tiny_root(tmp_path_factory):
     """A copy of the benchmark with tiny cells added as new files only."""
@@ -70,6 +90,14 @@ def tiny_root(tmp_path_factory):
         {"loop": "closed", "clients": 128, "warmup_s": 0.3}))
     (root / "chipbench/traffic/tiny_open.json").write_text(json.dumps(
         {"loop": "open", "rate_txn_s": 400, "warmup_s": 0.3}))
+    # a mix, a configuration and service settings that are all new files
+    (root / "chipbench/mixes/bank_audit.py").write_text(BANK_AUDIT)
+    (root / "chipbench/configs/tiny_audit.json").write_text(json.dumps(
+        dict(TINY, name="tiny_audit", service={"max_queue": 96},
+             mix={"kind": "bank_audit", "weights": [0, 15, 15, 25, 15, 15],
+                  "n_ops": 4})))
+    (root / "chipbench/configs/tiny_bad_service.json").write_text(json.dumps(
+        dict(TINY, name="tiny_bad_service", service={"no_such_setting": 1})))
     (root / "chipbench/metrics/commits_per_wave.py").write_text(
         "def read(ctx):\n"
         "    w = ctx.window\n"
@@ -80,7 +108,9 @@ def tiny_root(tmp_path_factory):
         {"name": "tiny_bank.closed", "config": "tiny_bank",
          "traffic": "tiny_closed", "chips": 1, "why": "test"},
         {"name": "tiny.open", "config": "tiny_ycsb",
-         "traffic": "tiny_open", "chips": 1, "why": "test"}]
+         "traffic": "tiny_open", "chips": 1, "why": "test"},
+        {"name": "tiny_audit.closed", "config": "tiny_audit",
+         "traffic": "tiny_closed", "chips": 1, "why": "test"}]
     spec["end_to_end"][0]["workloads"] = [w["name"]
                                           for w in spec["workloads"]]
     for m in spec["end_to_end"]:
@@ -90,7 +120,8 @@ def tiny_root(tmp_path_factory):
         {"name": "commits_per_wave", "unit": "ratio", "better": "higher",
          "source": "program_counter", "layer": "block program",
          "moves": "goodput_txn_s",
-         "workloads": ["tiny.closed", "tiny_bank.closed", "tiny.open"]})
+         "workloads": ["tiny.closed", "tiny_bank.closed", "tiny.open",
+                       "tiny_audit.closed"]})
     (root / "BENCHMARK.json").write_text(json.dumps(spec))
     return str(root)
 
@@ -110,6 +141,108 @@ def test_new_files_are_picked_up_by_name(tiny_root, cell):
     assert list(line)[-1] == "checks"
     assert all(c["limit"] == 0 for c in line["checks"].values())
     assert line["device"]["platform"] == "cpu"
+
+
+@pytest.mark.parametrize("forced", [0, 1])
+def test_a_new_mix_file_checks_its_own_count(tiny_root, monkeypatch,
+                                             forced):
+    """A cell whose mix, configuration and service settings are all new
+    files runs through ``run_cell``; the mix's own count stands under
+    ``checks`` with limit 0 and decides ``correct``."""
+    monkeypatch.setenv("BANK_AUDIT_FORCED", str(forced))
+    line = run(tiny_root, "tiny_audit.closed")
+    checks = line["checks"]
+    assert checks["audit_forced"] == {"value": forced, "limit": 0}
+    assert {"exactly_once", "final_value", "ring_version",
+            "never_answered"} <= set(checks)
+    assert all(c["value"] == 0 for k, c in checks.items()
+               if k != "audit_forced")
+    assert line["correct"] is (forced == 0)
+
+
+def test_service_settings_come_from_the_configuration(tiny_root):
+    cfg = dict(harness.load_config("tiny_audit", tiny_root), chips=1)
+    assert cfg["service"] == {"max_queue": 96}
+    served = harness.Served(cfg, 3, kernels="jnp")
+    assert served.svc.former.max_queue == 96       # 4 * T = 64 unset
+    txns = gen.draw(dict(harness.mix_of(cfg), kind="smallbank"), 1, 8)
+    load = harness.Load(served, {"loop": "closed", "clients": 8}, txns,
+                        None)
+    assert load.room() == 96
+    bad = {"name": "bad", "config": "tiny_bad_service",
+           "traffic": "tiny_closed", "chips": 1}
+    with pytest.raises(TypeError, match="no_such_setting"):
+        harness.run_cell(bad, 1, 0.5, False, time.perf_counter(),
+                         harness.load_spec(tiny_root), kernels="jnp",
+                         root=tiny_root)
+
+
+def test_warm_up_blocks_take_the_submitted_rows_shape(tiny_root):
+    cfg = dict(harness.load_config("tiny_ycsb", tiny_root), chips=1)
+    served = harness.Served(cfg, 3, kernels="jnp")
+    nop = served.nop_block(2, (4,))
+    assert nop.op_val.shape == nop.op_kind.shape == (2, 16, 4)
+    assert nop.host.shape == nop.tid.shape == (2, 16)
+    assert not nop.op_kind.any() and (nop.tid > 0).all()
+    assert served.nop_block(1, (4, 25)).op_val.shape == (1, 16, 4, 25)
+    served.svc.nop_block = lambda B: ("the service's own", B)
+    assert served.nop_block(4, (4,)) == ("the service's own", 4)
+
+
+def test_every_row_output_and_store_field_reaches_the_check():
+    """A program whose waves return a value per read op and whose store
+    keeps a record per version: both reach the check in ``extra``."""
+    import jax.numpy as jnp
+    import numpy as np
+    from typing import NamedTuple
+    from types import SimpleNamespace
+
+    class Out(NamedTuple):
+        status: np.ndarray
+        s: np.ndarray
+        c: np.ndarray
+        read_key: np.ndarray
+        read_cid: np.ndarray
+        read_rec: np.ndarray     # [T, O, W]: the record each read saw
+        write_key: np.ndarray
+        write_cid: np.ndarray
+        msgs: np.ndarray         # scalar: not a row
+
+    T, O, W, V, n_keys = 4, 2, 3, 2, 5
+    waves = []
+    for w in range(2):
+        r = np.arange(T) + 10 * w
+        waves.append((r + 100, Out(
+            r, r + 1, r + 2, np.stack([r] * O, 1), np.stack([r + 3] * O, 1),
+            np.broadcast_to(r[:, None, None], (T, O, W)) * 7,
+            np.stack([r + 4] * O, 1), np.stack([r + 5] * O, 1),
+            np.int32(w))))
+    h = harness.history_of(SimpleNamespace(history=waves))
+    assert h.tid.tolist() == [100, 101, 102, 103, 110, 111, 112, 113]
+    assert h.write_cid[:, 0].tolist() == (h.tid - 95).tolist()
+    assert list(h.extra) == ["read_rec"]
+    assert h.extra["read_rec"].shape == (2 * T, O, W)
+    assert h.extra["read_rec"][5, 1, 2] == 11 * 7
+
+    class Rings(NamedTuple):
+        val: jnp.ndarray
+        tid: jnp.ndarray
+        cid: jnp.ndarray
+        rec: jnp.ndarray         # [n_keys, V, W]: a record per version
+        head: jnp.ndarray
+        clock: jnp.ndarray       # not a per-key field
+
+    k = jnp.arange(n_keys, dtype=jnp.int32)
+    rings = Rings(jnp.stack([k] * V, 1), jnp.stack([k + 1] * V, 1),
+                  jnp.stack([k + 2] * V, 1),
+                  jnp.broadcast_to(k[:, None, None], (n_keys, V, W)) * 3,
+                  k % V, jnp.zeros(2, jnp.int32))
+    store, devices = harness.store_of(rings, n_keys)
+    assert store.cid[:, 0].tolist() == [2, 3, 4, 5, 6]
+    assert list(store.extra) == ["rec"]
+    assert store.extra["rec"].shape == (n_keys, V, W)
+    assert int(store.extra["rec"][4, 1, 2]) == 12
+    assert len(devices) == 1
 
 
 def test_a_host_stall_delays_open_arrivals_and_sheds_none(tiny_root,

@@ -50,7 +50,7 @@ def test_existing_readers_read_what_they_read_before(raw):
     assert read("read_phase_roofline") == pytest.approx(3.254975801520452,
                                                         rel=1e-12)
     assert read("device_idle_share") == pytest.approx(97.3953018, rel=1e-12)
-    scoped = observe.reduce_trace(raw, waves=8)
+    scoped = trace.reduce_scoped(raw, waves=8)
     assert scoped.busy_s == pytest.approx(s.busy_s, rel=1e-12)
     assert scoped.block_s == pytest.approx(s.module_s(["jit__scan_block"]))
     assert 0 < scoped.leaf_s <= scoped.busy_s
@@ -68,13 +68,13 @@ def test_existing_readers_read_what_they_read_before(raw):
     ("jit(_scan_block)/while/body/dynamic_update_slice", ""),
 ])
 def test_scope_of_keeps_the_named_scopes(stack, scope):
-    assert observe.scope_of(stack) == scope
+    assert trace.scope_of(stack) == scope
 
 
 def test_leaves_leave_out_loops_that_hold_operations():
     ops = ev([("%while.1", 0, 10), ("%while.2", 1, 9), ("%fusion.3", 2, 5),
               ("%copy.4", 5, 8), ("%fusion.5", 12, 14)])
-    assert observe.leaves(ops).tolist() == [False, False, True, True, True]
+    assert trace.leaves(ops).tolist() == [False, False, True, True, True]
 
 
 def test_scopes_claim_leaf_time_inside_the_block_program():
@@ -87,7 +87,7 @@ def test_scopes_claim_leaf_time_inside_the_block_program():
                                "commit_loop/while/body/closed_call/install/x",
               "%copy.3 = c": "jit(_scan_block)/while/body/closed_call/"
                              "commit_loop/while"}
-    s = observe.Scoped(devices, ev([]), stacks, waves=2,
+    s = trace.Scoped(devices, ev([]), stacks, waves=2,
                        summary=trace.Summary(devices, ev([]), 1.0, 2, 1))
     assert s.scope_s == pytest.approx({"commit_loop/install": 3e-9,
                                        "commit_loop": 2e-9, "": 1e-9})
@@ -104,7 +104,7 @@ def test_an_idle_gap_is_named_by_the_innermost_span():
     busy = np.array([[0.0, 10.0], [30.0, 40.0], [50.0, 60.0]])
     spans = ev([("chipbench.tick", 5, 45), ("repro.tick", 6, 44),
                 ("repro.retire_wait", 8, 20), ("repro.route", 20, 28)])
-    gaps = observe.innermost_gaps(busy, spans)
+    gaps = trace.innermost_gaps(busy, spans)
     assert gaps == pytest.approx({"repro.retire_wait": 10e-9,
                                   "repro.route": 8e-9, "repro.tick": 6e-9,
                                   "chipbench.tick": 1e-9, "no span": 5e-9})
@@ -155,12 +155,12 @@ def test_a_tiny_open_cell_splits_its_latency_on_one_clock(tiny_root,
     parts = out["latency_parts"]
     assert parts["n"] > 0
     mean = parts["mean_ms"]
-    assert sum(mean[p] for p in observe.PARTS) == pytest.approx(
+    assert sum(mean[p] for p in harness.PARTS) == pytest.approx(
         mean["latency"], rel=1e-9)
     at95 = parts["at_p95_request_ms"]
-    assert sum(at95[p] for p in observe.PARTS) == pytest.approx(
+    assert sum(at95[p] for p in harness.PARTS) == pytest.approx(
         at95["latency"], rel=1e-9)
-    assert parts["largest_at_p95"] in observe.PARTS
+    assert parts["largest_at_p95"] in harness.PARTS
     assert out["stage_n"]["tick"] == out["info"]["window"]["tick"]
     assert out["stage_n"]["dispatch"] == out["info"]["window"]["blocks"]
     r = out["readers"]
@@ -170,3 +170,52 @@ def test_a_tiny_open_cell_splits_its_latency_on_one_clock(tiny_root,
     assert r["commit_loop_ms_per_wave"] is None     # no trace on the CPU
     assert r["queue_wait_p95_ms"] == pytest.approx(parts["p95_ms"][
         "queue_wait"])
+
+
+SCOPED = os.path.join(os.path.dirname(__file__), "data",
+                      "scoped_t32.xplane.pb.gz")
+
+
+def test_a_traced_run_hands_the_readers_what_the_program_records(
+        tiny_root, monkeypatch):
+    """A traced run of a tiny open cell through ``run_cell``.  The CPU's
+    trace holds no device plane, so a trace recorded on one v5e chip, with
+    the program's named scopes, takes its place as the profiler stops; the
+    rest is the run's own.  Its context carries the window's stage timers,
+    each committed request's queue wait and block turn, and device time by
+    named scope, and the five readers read them."""
+    import glob
+
+    with gzip.open(SCOPED) as f:
+        recorded = f.read()
+    stop = trace.Tracer.stop
+
+    def stop_and_swap(self):
+        stop(self)
+        path, = glob.glob(os.path.join(self.out_dir, "**", "*.xplane.pb"),
+                          recursive=True)
+        with open(path, "wb") as f:
+            f.write(recorded)
+
+    monkeypatch.setattr(trace.Tracer, "stop", stop_and_swap)
+    spec = harness.load_spec(tiny_root)
+    out = harness.run_cell(harness.find_cell(spec, "tiny.open"),
+                           2 ** 31 + 13, 0.6, True, 0.0, spec,
+                           kernels="jnp", root=tiny_root)
+    line, ctx = out["line"], out["ctx"]
+    assert line["correct"] is True
+    assert ctx.stage_n["tick"] == ctx.window["tick"]
+    assert ctx.stage_n["submit"] == ctx.submitted > 0
+    assert ctx.stage_s["route"] > 0
+    committed = line["attempted"] - line["failed"]
+    assert len(ctx.queue_wait_s) == len(ctx.block_turn_s) == committed
+    assert (ctx.queue_wait_s >= 0).all() and (ctx.block_turn_s > 0).all()
+    assert isinstance(ctx.scoped, trace.Scoped)
+    assert ctx.scoped.waves == out["info"]["trace_waves"] > 0
+    assert ctx.scoped.scoped_s("commit_loop") > 0
+    for name in observe.READERS:
+        v = harness.metric_reader(name)(ctx)
+        assert v is not None and v > 0, name
+    assert any("[commit_loop" in op for op, _ in
+               line["breakdown"]["device_ops"])
+    assert set(line["breakdown"]) == {"device_ops", "idle_gaps"}

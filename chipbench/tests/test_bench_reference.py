@@ -12,8 +12,9 @@ def serve(kernels, n=600, seed=3):
     plane; returns what the reference compares."""
     from repro.service import TxnService
     from repro.service.stream import StreamingDriver
-    x = gen.ycsb(seed, n, n_nodes=8, keys_per_node=N_KEYS // 8, theta=0.99,
-                 read_frac=0.5, dist_frac=0.1, n_ops=O)
+    x = gen.load_mix("ycsb").draw(
+        seed, n, n_nodes=8, keys_per_node=N_KEYS // 8, theta=0.99,
+        read_frac=0.5, dist_frac=0.1, n_ops=O)
     svc = TxnService(n_keys=N_KEYS, n_versions=4, T=T, O=O, sched="postsi",
                      n_nodes=8, seed=seed, kernels=kernels)
     drv = StreamingDriver(svc, B=2, K=2)

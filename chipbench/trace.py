@@ -10,6 +10,12 @@ exactly the blocks dispatched inside it.  The reduction reads the
 * device seconds per operation name and per program (XLA module);
 * idle gaps — the stretches with no operation on the first chip, each
   named by the harness span (``chipbench.*``) that covers most of it.
+
+``Scoped`` reads the same file for what the program itself names: device
+seconds per named scope of the block program (``engine.run_wave_on``),
+from each operation's ``tf_op`` (``chipbench/xspace.py``), and idle gaps
+named by the innermost host span, the program's ``repro.*`` stages inside
+the harness's ``chipbench.*`` spans.
 """
 from __future__ import annotations
 
@@ -22,11 +28,19 @@ from typing import Dict, Iterable, List, Tuple
 
 import numpy as np
 
+from chipbench import xspace
+
 TRACE_S = 0.5             # seconds of serving the trace covers
 TOP = 10                  # entries in each list of the breakdown
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 SPAN_PREFIX = "chipbench."
+# the named scopes of ``engine.run_wave_on``, outermost first
+SCOPES = ("read_phase", "commit_loop", "newest", "validate", "install",
+          "bump_sid", "push_bounds", "record", "message_stats")
+SPAN_PREFIXES = ("chipbench.", "repro.")
+# block programs, as ``metrics/block_ms_per_wave.py`` names them
+BLOCK_PROGRAMS = ("jit__scan_block(", "jit_node_fn(")
 
 
 class Events:
@@ -41,16 +55,9 @@ class Events:
         return len(self.names)
 
 
-def read_xplane(path: str) -> Tuple[Dict[str, Dict[str, Events]],
-                                    Events]:
-    """Device lines per TPU chip (``{plane: {line: Events}}``) and the
-    harness's host spans, from one ``.xplane.pb`` file."""
-    from jax.profiler import ProfileData
-    return read_profile(ProfileData.from_file(path))
-
-
 def read_profile(prof) -> Tuple[Dict[str, Dict[str, Events]], Events]:
-    """``read_xplane`` for a loaded ``jax.profiler.ProfileData``."""
+    """Device lines per TPU chip (``{plane: {line: Events}}``) and the
+    harness's host spans, from a loaded ``jax.profiler.ProfileData``."""
     devices: Dict[str, Dict[str, Events]] = {}
     spans = ([], [], [])
     for plane in prof.planes:
@@ -189,14 +196,145 @@ class Tracer:
         jax.profiler.stop_trace()
 
     def reduce(self, n_devices: int, waves: int) -> Summary:
+        """The trace's ``Summary``; the file's bytes stay in ``raw``."""
         paths = glob.glob(os.path.join(self.out_dir, "**", "*.xplane.pb"),
                           recursive=True)
         if len(paths) != 1:
             raise RuntimeError(f"expected one trace file, found {paths}")
         t = time.perf_counter()
-        self.file_bytes = os.path.getsize(paths[0])
-        devices, spans = read_xplane(paths[0])
+        with open(paths[0], "rb") as f:
+            self.raw = f.read()
+        self.file_bytes = len(self.raw)
         shutil.rmtree(self.out_dir, ignore_errors=True)
+        from jax.profiler import ProfileData
+        devices, spans = read_profile(
+            ProfileData.from_serialized_xspace(self.raw))
         out = Summary(devices, spans, self.t1 - self.t0, waves, n_devices)
         self.reduce_s = time.perf_counter() - t
         return out
+
+
+# ------------------------------------------------- what the program names
+def scope_of(tf_op: str) -> str:
+    """The named scopes on an operation's name stack, joined by ``/``
+    (``commit_loop/install``); empty where it carries none."""
+    return "/".join(c for c in tf_op.split("/") if c in SCOPES)
+
+
+def host_spans(prof) -> Events:
+    """The harness's and the program's spans, under their full names."""
+    names, start, dur = [], [], []
+    for plane in prof.planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(SPAN_PREFIXES):
+                    names.append(e.name)
+                    start.append(e.start_ns)
+                    dur.append(e.duration_ns)
+    return Events(names, start, dur)
+
+
+def leaves(ev: Events) -> np.ndarray:
+    """True for the events that hold no other event of their line, so a
+    ``while`` or ``conditional`` does not count its body twice."""
+    order = np.lexsort((-ev.end, ev.start))
+    inner = np.zeros(len(ev), bool)
+    s, e = ev.start[order], ev.end[order]
+    inner[order[:-1]] = s[1:] < e[:-1]
+    return ~inner
+
+
+def innermost_gaps(busy: np.ndarray, spans: Events) -> Dict[str, float]:
+    """Idle seconds between device operations, each stretch put down to
+    the innermost host span that covers it (the one that started last)."""
+    out: Dict[str, float] = defaultdict(float)
+    for a, b in zip(busy[:-1, 1], busy[1:, 0]):
+        if b <= a:
+            continue
+        hit = np.nonzero((spans.start < b) & (spans.end > a))[0]
+        cuts = np.unique(np.clip(np.r_[a, b, spans.start[hit],
+                                       spans.end[hit]], a, b))
+        for x, y in zip(cuts[:-1], cuts[1:]):
+            cover = hit[(spans.start[hit] <= x) & (spans.end[hit] >= y)]
+            name = "no span"
+            if len(cover):
+                # latest start, then shortest: the innermost
+                i = cover[np.lexsort((spans.end[cover],
+                                      -spans.start[cover]))[0]]
+                name = spans.names[i]
+            out[name] += (y - x) * 1e-9
+    return dict(out)
+
+
+class Scoped:
+    """Device seconds by named scope and idle seconds by innermost span,
+    from one trace, on the first chip; beside them, busy time and idle
+    seconds as the harness reads them (``summary``, its ``Summary`` of the
+    same trace)."""
+
+    def __init__(self, devices: Dict[str, Dict[str, Events]],
+                 spans: Events, tf_op: Dict[str, str], waves: int,
+                 summary: Summary):
+        chip = min(devices, key=lambda p: int(p.rsplit(":", 1)[1]))
+        empty = Events([], [], [])
+        ops = devices[chip].get(OPS_LINE, empty)
+        mods = devices[chip].get(MODULES_LINE, empty)
+        self.waves = int(waves)
+        self.scope_of = {n: scope_of(tf_op.get(n, "")) for n in
+                         set(ops.names)}
+        leaf = leaves(ops) if len(ops) else np.zeros(0, bool)
+        self.scope_s: Dict[str, float] = defaultdict(float)
+        self.unscoped: Dict[str, float] = defaultdict(float)
+        for n, d, is_leaf in zip(ops.names, ops.end - ops.start, leaf):
+            if is_leaf:
+                self.scope_s[self.scope_of[n]] += d * 1e-9
+                if not self.scope_of[n]:
+                    self.unscoped[n] += d * 1e-9
+        self.scope_s = dict(self.scope_s)
+        self.leaf_s = sum(self.scope_s.values())
+        self.busy_s = summary.busy_s
+        self.block_s = sum(v for k, v in per_name(mods).items()
+                           if k.startswith(BLOCK_PROGRAMS))
+        self.ops = per_name(ops)
+        self.gaps = innermost_gaps(union(ops), spans)
+        self.harness_gaps = summary.gaps
+
+    def scoped_s(self, prefix: str = "") -> float:
+        """Leaf-op seconds under scopes that start with ``prefix`` (every
+        scope where it is empty)."""
+        return sum(v for k, v in self.scope_s.items()
+                   if k and k.startswith(prefix))
+
+    def breakdown(self, top: int = TOP) -> dict:
+        """The operations that took most device time, each with its scope,
+        the leaf operations that took most under no scope, and the idle
+        gaps by innermost span and by harness span."""
+        def label(name):
+            short = name.split(" = ")[0]
+            scope = self.scope_of.get(name)
+            return f"{short} [{scope}]" if scope else short
+        rank = lambda d: sorted(d.items(), key=lambda kv: -kv[1])[:top]
+        return {"device_ops": [[label(k), v] for k, v in rank(self.ops)],
+                "unscoped_ops": [[k.split(" = ")[0], v]
+                                 for k, v in rank(self.unscoped)],
+                "idle_gaps": rank(self.gaps),
+                "idle_gaps_by_harness_span": rank(self.harness_gaps)}
+
+    def summary(self) -> dict:
+        return {"busy_s": self.busy_s, "leaf_s": self.leaf_s,
+                "block_s": self.block_s, "waves": self.waves,
+                "scope_s": self.scope_s,
+                "claimed_share": (self.scoped_s() / self.block_s
+                                  if self.block_s else None),
+                **self.breakdown()}
+
+
+def reduce_scoped(raw: bytes, waves: int) -> Scoped:
+    """``Scoped`` of one serialized trace (``Tracer.raw``)."""
+    from jax.profiler import ProfileData
+    prof = ProfileData.from_serialized_xspace(raw)
+    devices, spans = read_profile(prof)
+    return Scoped(devices, host_spans(prof), xspace.tf_ops(raw), waves,
+                  Summary(devices, spans, 1.0, waves, 1))
