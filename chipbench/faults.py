@@ -54,10 +54,16 @@ def no_validation():
 
 def frozen_state():
     """A block program that acknowledges its waves and leaves the store as
-    it was."""
+    it was.  The store is copied on the device before each block and the
+    copy put back after it, since the program may donate the store it is
+    given.  The copy doubles the store's memory, which a store of
+    gigabytes does not have room for: this fault is a tool for the CPU and
+    small stores."""
     def make(old):
         def run_block(self, stacked):
-            store = self.store
+            import jax
+            import jax.numpy as jnp
+            store = jax.tree.map(jnp.copy, self.store)
             out = old(self, stacked)
             self.store = store
             return out

@@ -7,7 +7,8 @@ configuration gives it, so that a new cell comes as new files only:
 
     chipbench/configs/<config>.json    deployment: sizes, mix, service
     chipbench/traffic/<traffic>.json   arrivals: open rate or closed clients
-    chipbench/mixes/<kind>.py          transactions: ``draw`` and ``check``
+    chipbench/mixes/<kind>.py          transactions: ``draw``, ``check``, and
+                                       optionally ``initial``, a loaded table
     chipbench/metrics/<metric>.py      reader: ``read(ctx) -> float | None``
 """
 from __future__ import annotations

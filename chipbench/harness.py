@@ -7,14 +7,18 @@ mix or per-layer metric lives in a file of its own, found by its name
 
     chipbench/configs/<config>.json    deployment: sizes, mix, service
     chipbench/traffic/<traffic>.json   arrivals: open rate or closed clients
-    chipbench/mixes/<kind>.py          transactions: ``draw`` and ``check``
+    chipbench/mixes/<kind>.py          transactions: ``draw``, ``check``, and
+                                       optionally ``initial``, a loaded table
     chipbench/metrics/<metric>.py      reader: ``read(ctx) -> float | None``
 
 A configuration's optional ``service`` object is passed to ``TxnService``
-as keyword arguments.  The harness hands the program each generated row as
-it is and reads back every per-row output and every per-key store field,
-whatever their trailing shapes, so a mix whose records are wider than one
-int32 needs no edit here.
+as keyword arguments, and a mix's loaded table as the keyword ``initial``.
+The harness hands the program each generated row as it is and reads back
+every per-row output, every per-key store field and every request's
+``read_val``, whatever their trailing shapes, so a mix whose records are
+wider than one int32 needs no edit here.  It holds no store of the
+program's across a block, so the program may donate its store to the
+block program.
 
 The program serves on logical ticks (``TxnService``, ``StreamingDriver``);
 this harness supplies the wall clock.  It calls ``TxnService.submit`` and
@@ -112,7 +116,9 @@ class CompileClock:
 class Served:
     """The system under test and the stamps the harness keeps of it."""
 
-    def __init__(self, cfg: dict, seed: int, kernels=None):
+    def __init__(self, cfg: dict, seed: int, kernels=None, initial=None):
+        """``initial``, a mix's loaded table (``gen.initial``), reaches
+        ``TxnService`` as the keyword ``initial`` where given."""
         import jax
         from repro.service import TxnService
         from repro.service.stream import StreamingDriver
@@ -125,7 +131,8 @@ class Served:
             n_keys=cfg["n_keys"], n_versions=cfg["n_versions"], T=cfg["T"],
             O=cfg["O"], sched=cfg["scheduler"],
             n_nodes=cfg["n_nodes"], seed=seed % 2 ** 32, mesh=mesh,
-            kernels=kernels, **cfg.get("service", {}))
+            kernels=kernels, **cfg.get("service", {}),
+            **({} if initial is None else {"initial": initial}))
         self.drv = StreamingDriver(self.svc, B=cfg["B"], K=cfg["K"])
         self.svc.stream = self.drv
         jax.block_until_ready(self.svc.store)
@@ -149,14 +156,19 @@ class Served:
 
     def warm_shapes(self, val_row: tuple) -> None:
         """Run every block shape the driver can dispatch, on NOP waves and
-        with the argument types the service passes (``nop_block``), and
-        drop the results: the service's own state is left as it was.  On
-        a mesh each shape runs twice, first on the service's initial clock
-        and then on the clock a block returns, because there the two carry
-        different shardings and so are different programs."""
+        with the argument types the service passes (``nop_block``), on the
+        service's live store, and keep the store each block returns: the
+        program may donate the store it is given, and a NOP block leaves
+        every field of it as it was.  The wave index, the block count and
+        the clock are put back as they were found, so the program may
+        donate its store but not its clock.  On a mesh each shape runs
+        twice, first on the service's initial clock and then on the clock
+        a block returns, because there the two carry different shardings
+        and so are different programs; after the first block the store
+        carries a block's output sharding, the one serving uses."""
         import jax
         svc, cfg = self.svc, self.cfg
-        wave_idx, clock, store = svc.wave_idx, svc.clock, svc.store
+        wave_idx, blocks, clock = svc.wave_idx, svc.blocks, svc.clock
         for B in BLOCK_SHAPES:
             if B > cfg["B"]:
                 continue
@@ -164,9 +176,9 @@ class Served:
             for _ in range(2 if cfg["chips"] > 1 else 1):
                 outs, _ = svc._run_block(nop)
                 jax.block_until_ready(outs)
-                svc.wave_idx, svc.store = wave_idx, store
+                svc.wave_idx = wave_idx
             svc.clock = clock
-        svc.blocks = 0
+        svc.blocks = blocks
         jax.block_until_ready(svc.store)
 
     def tick(self) -> None:
@@ -350,14 +362,28 @@ def history_of(svc) -> reference.History:
 
 
 def answers_of(reqs) -> reference.Answers:
+    """The program's answer to each request.  Where any request carries a
+    ``read_val`` (the values its committed execution read, as the service
+    routed them), ``Answers.extra`` holds them stacked, ``read_val`` of
+    ``[n, O, ...]`` with zeros where a request carries none, and the
+    ``[n]`` mask ``has_read_val`` of those that do."""
     n = len(reqs)
     committed = np.fromiter((r.status == "committed" for r in reqs), bool, n)
     last = np.fromiter((r.tid for r in reqs), np.int64, n)
     counts = np.fromiter((len(r.tids) for r in reqs), np.int64, n)
     exec_tid = np.fromiter((t for r in reqs for t in r.tids), np.int64,
                            int(counts.sum()))
+    extra = {}
+    vals = [getattr(r, "read_val", None) for r in reqs]
+    has = np.fromiter((v is not None for v in vals), bool, n)
+    if has.any():
+        got = np.stack([v for v in vals if v is not None])
+        read_val = np.zeros((n,) + got.shape[1:], got.dtype)
+        read_val[has] = got
+        extra = {"read_val": read_val, "has_read_val": has}
     return reference.Answers(committed, last,
-                             np.repeat(np.arange(n), counts), exec_tid)
+                             np.repeat(np.arange(n), counts), exec_tid,
+                             extra=extra)
 
 
 def store_of(store, n_keys: int) -> tuple:
@@ -365,12 +391,9 @@ def store_of(store, n_keys: int) -> tuple:
     shards (one shard on one chip); also the devices that hold them.
     Every field with one row per key (as many as ``val``, a mesh's padding
     rows cut off) is read: the named ones as ``Store``'s fields and any
-    other in ``Store.extra``."""
-    def read(a):
-        shards = sorted(a.addressable_shards,
-                        key=lambda s: s.index[0].start or 0)
-        return np.concatenate([np.asarray(s.data) for s in shards])[:n_keys]
-    rows = {f: read(a) for f, a in store._asdict().items()
+    other in ``Store.extra``.  Each field reaches the host once, as one
+    read-only array that JAX fills shard by shard."""
+    rows = {f: np.asarray(a)[:n_keys] for f, a in store._asdict().items()
             if a.ndim and a.shape[0] == store.val.shape[0]}
     named = {f: rows.pop(f) for f in reference.Store._fields
              if f != "extra"}
@@ -415,9 +438,10 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
     else:
         due = None
         txns = gen.draw(mix_of(cfg), seed, STREAM_LEN, root)
+    table = gen.initial(mix_of(cfg), seed, cfg["n_keys"], root)
 
     with CompileClock() as clk, collector_off():
-        served = Served(cfg, seed, kernels=kernels)
+        served = Served(cfg, seed, kernels=kernels, initial=table)
         served.warm_shapes(txns.val.shape[1:])
         load = Load(served, traffic, txns, due)
         tw0 = load.t0 + traffic["warmup_s"]
@@ -515,7 +539,8 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
     n_keys = cfg["n_keys"]
     del served, load, svc
     _gc.collect()
-    checks = mix.check(sub, ans, hist, store)
+    checks = mix.check(sub, ans, hist, store,
+                       **({} if table is None else {"initial": table}))
     checks["never_answered"] = unanswered
     if cfg["chips"] > 1:
         checks["shard_devices"] = abs(len(store_devs) - cfg["chips"])

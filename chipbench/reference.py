@@ -6,9 +6,10 @@ itself generated and submitted (``Txns``), the program's answers to those
 requests (status and the TIDs each execution ran under), the served
 history (per-row status, start/commit times, read and write keys with the
 CIDs read and stamped) and the final store, read back shard by shard.
-Every other per-row output of the program and every other per-key field
-of its store arrive in ``History.extra`` and ``Store.extra``, for a mix's
-own check (``chipbench/mixes/``).  ``check`` counts, each with limit 0:
+Every other per-row output of the program, every other per-key field of
+its store and the values each request was answered with arrive in
+``History.extra``, ``Store.extra`` and ``Answers.extra``, for a mix's own
+check (``chipbench/mixes/``).  ``check`` counts, each with limit 0:
 
 * ``exactly_once`` — every acknowledged request owns exactly one committed
   history row (the one of its last execution) and every other request
@@ -57,6 +58,7 @@ class Answers(NamedTuple):
     last_tid: np.ndarray   # [n] TID of the last execution (-1: never ran)
     exec_req: np.ndarray   # [E] request index of every execution
     exec_tid: np.ndarray   # [E] TID of that execution
+    extra: Dict[str, np.ndarray] = {}   # every other [n, ...] answer
 
 
 class Store(NamedTuple):

@@ -73,6 +73,38 @@ def check(txns, answers, history, store):
 """
 
 
+# A mix of a test's own that brings its loaded table: SmallBank's draw, a
+# table drawn from the seed, and a check that replays the committed
+# requests from that table.  ``initial_lost`` counts untouched keys whose
+# newest version does not hold their loaded value.
+BANK_LOADED = """
+import os
+
+import numpy as np
+
+from chipbench import gen, reference
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+bank = gen.load_mix("smallbank", ROOT)
+draw = bank.draw
+
+
+def initial(seed, *, n_keys, n_nodes, keys_per_node, **mix):
+    rng = np.random.default_rng([seed, 7])
+    return rng.integers(1, 1000, n_nodes * keys_per_node).astype(np.int32)
+
+
+def check(txns, answers, history, store, initial):
+    newest = store.val[np.arange(len(initial)), store.head]
+    untouched = store.cid[np.arange(len(initial)), store.head] == 0
+    out = reference.check(txns, answers, history,
+                          store._replace(val=store.val - initial[:, None]))
+    out["initial_lost"] = int((newest != initial)[untouched].sum())
+    return out
+"""
+
+
 @pytest.fixture(scope="module")
 def tiny_root(tmp_path_factory):
     """A copy of the benchmark with tiny cells added as new files only."""
@@ -96,6 +128,11 @@ def tiny_root(tmp_path_factory):
         dict(TINY, name="tiny_audit", service={"max_queue": 96},
              mix={"kind": "bank_audit", "weights": [0, 15, 15, 25, 15, 15],
                   "n_ops": 4})))
+    (root / "chipbench/mixes/bank_loaded.py").write_text(BANK_LOADED)
+    (root / "chipbench/configs/tiny_loaded.json").write_text(json.dumps(
+        dict(TINY, name="tiny_loaded",
+             mix={"kind": "bank_loaded", "weights": [0, 15, 15, 25, 15, 15],
+                  "n_ops": 4})))
     (root / "chipbench/configs/tiny_bad_service.json").write_text(json.dumps(
         dict(TINY, name="tiny_bad_service", service={"no_such_setting": 1})))
     (root / "chipbench/metrics/commits_per_wave.py").write_text(
@@ -110,6 +147,8 @@ def tiny_root(tmp_path_factory):
         {"name": "tiny.open", "config": "tiny_ycsb",
          "traffic": "tiny_open", "chips": 1, "why": "test"},
         {"name": "tiny_audit.closed", "config": "tiny_audit",
+         "traffic": "tiny_closed", "chips": 1, "why": "test"},
+        {"name": "tiny_loaded.closed", "config": "tiny_loaded",
          "traffic": "tiny_closed", "chips": 1, "why": "test"}]
     spec["end_to_end"][0]["workloads"] = [w["name"]
                                           for w in spec["workloads"]]
@@ -121,7 +160,7 @@ def tiny_root(tmp_path_factory):
          "source": "program_counter", "layer": "block program",
          "moves": "goodput_txn_s",
          "workloads": ["tiny.closed", "tiny_bank.closed", "tiny.open",
-                       "tiny_audit.closed"]})
+                       "tiny_audit.closed", "tiny_loaded.closed"]})
     (root / "BENCHMARK.json").write_text(json.dumps(spec))
     return str(root)
 
@@ -187,6 +226,103 @@ def test_warm_up_blocks_take_the_submitted_rows_shape(tiny_root):
     assert served.nop_block(1, (4, 25)).op_val.shape == (1, 16, 4, 25)
     served.svc.nop_block = lambda B: ("the service's own", B)
     assert served.nop_block(4, (4,)) == ("the service's own", 4)
+
+
+def test_warm_up_leaves_the_store_bit_for_bit(tiny_root):
+    """Every NOP block of the warm-up, run on a store that has served
+    transactions, leaves each store field as it was, and the wave index,
+    block count and clock are put back."""
+    import jax
+    import numpy as np
+    cfg = dict(harness.load_config("tiny_ycsb", tiny_root), chips=1)
+    served = harness.Served(cfg, 3, kernels="jnp")
+    svc = served.svc
+    txns = gen.draw(harness.mix_of(cfg), 5, 200, tiny_root)
+    for i in range(len(txns)):
+        svc.submit(txns.kind[i], txns.key[i], txns.val[i],
+                   int(txns.host[i]))
+        if i % 50 == 49:      # within what admission holds (4 * T)
+            served.drv.drain()
+    assert svc.committed == 200
+    host = lambda: {f: np.array(a) for f, a in svc.store._asdict().items()}
+    before, marks = host(), (svc.wave_idx, svc.blocks)
+    clock = np.array(svc.clock)
+    served.warm_shapes(txns.val.shape[1:])
+    after = host()
+    assert before.keys() == after.keys()
+    for f in before:
+        assert before[f].dtype == after[f].dtype
+        np.testing.assert_array_equal(before[f], after[f], err_msg=f)
+    assert (svc.wave_idx, svc.blocks) == marks
+    np.testing.assert_array_equal(np.array(svc.clock), clock)
+    jax.block_until_ready(svc.store)
+
+
+def _loading_service(loads):
+    """A ``TxnService`` that takes the keyword ``initial`` and, if
+    ``loads``, writes it into every key's version 0."""
+    from repro.service import TxnService
+
+    class Loading(TxnService):
+        def __init__(self, *a, initial, **kw):
+            super().__init__(*a, **kw)
+            if loads:
+                self.store = self.store._replace(
+                    val=self.store.val.at[:, 0].set(initial))
+    return Loading
+
+
+@pytest.mark.parametrize("program", ["loads", "ignores", "lacks"])
+def test_a_mix_brings_its_loaded_table(tiny_root, monkeypatch, program):
+    """A mix that exports ``initial`` has its table reach the program as
+    the keyword ``initial`` and its check as ``initial=``: a program that
+    loads it serves correct, one that ignores it fails the mix's own
+    count, and one without the keyword fails at set-up."""
+    import repro.service
+    if program != "lacks":
+        monkeypatch.setattr(repro.service, "TxnService",
+                            _loading_service(program == "loads"))
+        line = run(tiny_root, "tiny_loaded.closed")
+        checks = line["checks"]
+        assert "initial_lost" in checks
+        assert line["correct"] is (program == "loads")
+        assert (checks["initial_lost"]["value"] > 0) is (program == "ignores")
+        return
+    with pytest.raises(TypeError, match="initial"):
+        run(tiny_root, "tiny_loaded.closed")
+
+
+def test_a_loaded_table_must_have_a_row_per_key(tiny_root):
+    mix = {"kind": "bank_loaded", "n_nodes": 8, "keys_per_node": 384,
+           "weights": [0, 1, 1, 1, 1, 1]}
+    table = gen.initial(mix, 2 ** 31 + 9, 3072, tiny_root)
+    assert table.shape == (3072,) and table.dtype == "int32"
+    assert (table == gen.initial(mix, 2 ** 31 + 9, 3072, tiny_root)).all()
+    with pytest.raises(harness.BenchError, match="initial"):
+        gen.initial(mix, 1, 3000, tiny_root)
+    assert gen.initial(dict(mix, kind="smallbank"), 1, 3072) is None
+
+
+def test_answers_carry_each_requests_read_values():
+    """Requests that carry ``read_val`` have it stacked in
+    ``Answers.extra``, beside the mask of those that do; where none does,
+    ``extra`` stays empty."""
+    import numpy as np
+    from types import SimpleNamespace
+    req = lambda tids, **kw: SimpleNamespace(
+        status="committed", tid=tids[-1], tids=tids, **kw)
+    rec = lambda k: np.arange(8, dtype=np.int32).reshape(4, 2) + 100 * k
+    reqs = [req([5], read_val=rec(1)), req([6, 9]),
+            req([7], read_val=None), req([8], read_val=rec(3))]
+    ans = harness.answers_of(reqs)
+    assert ans.exec_req.tolist() == [0, 1, 1, 2, 3]
+    assert ans.extra["has_read_val"].tolist() == [True, False, False, True]
+    got = ans.extra["read_val"]
+    assert got.shape == (4, 4, 2) and got.dtype == np.int32
+    np.testing.assert_array_equal(got[0], rec(1))
+    np.testing.assert_array_equal(got[3], rec(3))
+    assert not got[1].any() and not got[2].any()
+    assert harness.answers_of(reqs[1:3]).extra == {}
 
 
 def test_every_row_output_and_store_field_reaches_the_check():
@@ -297,6 +433,31 @@ def test_correct_comes_out_false_on_a_broken_path(tiny_root, broken):
 @BROKEN
 def test_smallbank_comes_out_false_on_a_broken_path(tiny_root, broken):
     sees_the_fault(tiny_root, "tiny_bank.closed", broken)
+
+
+@pytest.fixture
+def donating(monkeypatch):
+    """The block program donates the store it is given, as a program whose
+    store fills most of the chip must."""
+    import jax
+    from repro.core import engine
+    monkeypatch.setattr(engine, "_scan_block", jax.jit(
+        engine._scan_block.__wrapped__, donate_argnums=(0,),
+        static_argnames=("sched", "skew", "gc_track", "gc_block",
+                         "kernels")))
+
+
+@pytest.mark.parametrize("cell,broken", [
+    ("tiny.open", None), ("tiny.closed", None),
+    ("tiny.closed", faults.no_validation),
+    ("tiny.closed", faults.frozen_state),
+    ("tiny.closed", faults.half_batch),
+    ("tiny.closed", faults.altered_value)],
+    ids=["open", "closed", "control", "frozen_state", "half_batch",
+         "altered_value"])
+def test_a_program_that_donates_its_store(tiny_root, donating, cell,
+                                          broken):
+    sees_the_fault(tiny_root, cell, broken)
 
 
 MESH = r"""
