@@ -3,8 +3,10 @@
 The paper's asynchronous shared-nothing execution is mapped onto *waves*
 (DESIGN.md §2): a wave is a batch of transactions whose lifespans all overlap
 — they read the wave-start snapshot in parallel, keep write sets private
-(paper §IV-C) and then commit one-by-one in a deterministic order, which is
-where the paper's rules fire:
+(paper §IV-C) and then commit in a deterministic order, which is where the
+paper's rules fire (one loop step commits a segment of consecutive
+transactions no two of which share a key, with the same outcome as one by
+one; DESIGN.md §2):
 
   read phase   — CV rule 4 / PostSI §IV-B CID visibility + PostSI rule 3
                  (raise s_lo/c_lo to the CID of every version read),
@@ -53,8 +55,9 @@ from jax import lax
 
 from repro.kernels import KernelConfig, register_cache_clear, resolve
 from .commit_phase import (ABORTED, COMMITTED, NOP, READ, RMW, RUNNING, WRITE,
-                           creator_slots, lost_update, ongoing_readers_of,
-                           postsi_bounds, push_bounds, rw_edge_to_creator)
+                           creator_slots, last_sharer, lost_update,
+                           ongoing_readers, postsi_bounds, push_bounds,
+                           rw_edge_to_creator, segment_end)
 from .store import (INF, MVStore, PlacementArrays, as_placement_arrays,
                     node_of_key)
 from .substrate import LocalSubstrate
@@ -85,6 +88,7 @@ class WaveOut(NamedTuple):
     waits: jax.Array       # scalar: clock-si skew waits
     evicted_visible: jax.Array  # scalar: ring-slot reuses of still-visible
                                 # versions (GC watermark violations, §8)
+    commit_steps: jax.Array  # scalar: commit-loop steps (one per segment)
 
 
 def run_wave_on(sub, store: MVStore, wave: Wave, wave_idx: jax.Array,
@@ -118,7 +122,19 @@ def run_wave_on(sub, store: MVStore, wave: Wave, wave_idx: jax.Array,
     operation (and what the static-vs-elastic differentials pin).
     Placement-aware load/occupancy accounting is host-side, from
     ``PlacementMap.owner`` (repro.placement).  Everything the caller sees
-    (``read_key``/``write_key``, statuses, timestamps) stays LOGICAL."""
+    (``read_key``/``write_key``, statuses, timestamps) stays LOGICAL.
+
+    Commits follow wave-local index order, one loop step per *segment*:
+    the longest run of consecutive undecided transactions of which no two
+    share a key (``commit_phase.last_sharer`` / ``segment_end``), committed
+    by one step vectorised over its lanes.  A transaction's commit reads
+    and writes only the store rows of its own keys, the bounds and status
+    of transactions sharing a key with it, and the clock; so no lane of a
+    segment sees another's effects, rule 4(b)'s pushes are a max and a min,
+    and the clocked schedulers' ``c = clock + 1`` chain is a count of the
+    segment's earlier commits.  Outcomes and store are bit-identical to one
+    transaction a step (tests/test_commit_segments.py); the step count is
+    ``WaveOut.commit_steps``."""
     assert sched in SCHEDULERS, sched
     T, O = wave.op_kind.shape
     clock0 = clock          # wave-entry clock = snapshot time for clocked scheds
@@ -165,36 +181,46 @@ def run_wave_on(sub, store: MVStore, wave: Wave, wave_idx: jax.Array,
         s_hi0 = jnp.full((T,), INF, jnp.int32)
 
     # --------------------------------------------------------------- commits
-    # deterministic commit order = wave-local index (tids ascend within wave)
-    def commit_one(i, carry):
-        (st, s_lo, s_hi, c_lo, status, s_arr, c_arr, wcid, clk, ev_cnt) = carry
+    # deterministic commit order = wave-local index (tids ascend within wave),
+    # one step per segment [a, b) of key-disjoint transactions (docstring):
+    # every rule is evaluated for every lane, and the lanes of [a, b) decide
+    with jax.named_scope("commit_loop"):
+        with jax.named_scope("segment"):
+            prev = last_sharer(keys, wave.op_kind != NOP)
+            b0 = segment_end(prev, 0)
+        lane = jnp.arange(T)
+        tid_b = jnp.broadcast_to(wave.tid[:, None], (T, O))
+        val_new = jnp.where(wave.op_kind == RMW, r_val + wave.op_val,
+                            wave.op_val)
+
+    def commit_segment(carry):
+        (a, b, steps, st, s_lo, s_hi, c_lo, status, s_arr, c_arr, wcid, clk,
+         ev_cnt) = carry
         with jax.named_scope("newest"):
-            active = status[i] == RUNNING
-            k_i = keys[i]                                         # [O] logical
-            pk_i = pkeys[i]                                       # [O] physical
-            w_i = is_write[i]
-            r_i = is_read[i]
-            nv_val, nv_tid, nv_cid, nv_sid, nv_slot = sub.read_newest(st, pk_i)
+            seg = (lane >= a) & (lane < b)
+            nv_val, nv_tid, nv_cid, nv_sid, nv_slot = sub.read_newest(st,
+                                                                      pkeys)
             # map newest creators to wave-local ids (or -1 if older wave)
             local, creator_committed = creator_slots(nv_tid, wave.tid[0], T,
                                                      status)
 
         with jax.named_scope("validate"):
             # lost update: an RMW whose read version is no longer newest
-            lost = lost_update(r_i, w_i, nv_cid, r_cid[i])
+            lost = lost_update(is_read, is_write, nv_cid, r_cid)
             # CV rule 5(ii): newest creator has an rw edge from me (I read
             # data it overwrote) -> it is invisible to me -> cannot overwrite
             # its version
             if sched in ("postsi", "cv"):
                 rw_to_creator = rw_edge_to_creator(
-                    w_i, local, creator_committed, potential[i])
+                    is_write, local, creator_committed, potential)
             else:
                 rw_to_creator = jnp.array(False)
 
             if sched in ("si", "dsi", "clocksi", "optimal"):
                 # first-committer-wins: any write over a same-wave commit
                 # aborts
-                ww_conc = (w_i & (local >= 0) & creator_committed).any()
+                ww_conc = (is_write & (local >= 0) & creator_committed).any(
+                    axis=-1)
             else:  # postsi / cv may overwrite a committed peer (Fig.1 t2/t3)
                 ww_conc = jnp.array(False)
 
@@ -204,69 +230,72 @@ def run_wave_on(sub, store: MVStore, wave: Wave, wave_idx: jax.Array,
                 # incremental snapshot: a *remote* read whose key was
                 # meanwhile overwritten implies a local/global timestamp
                 # mismatch -> abort
-                remote = node_of_key(k_i, n_nodes) != wave.host[i]
-                stale_remote = (r_i & remote & (nv_cid != r_cid[i])).any()
+                remote = node_of_key(keys, n_nodes) != wave.host[:, None]
+                stale_remote = (is_read & remote & (nv_cid != r_cid)).any(
+                    axis=-1)
                 abort = abort | stale_remote
 
             if sched == "postsi":
                 # rules 3/4(a)/5 (commit_phase.postsi_bounds); SIDs of read
                 # slots are re-gathered: peers may have bumped them while we
                 # ran
-                cur_sid = sub.read_sid(st, pk_i, r_slot[i])
-                ongoing_reader = ongoing_readers_of(i, potential, status)
+                cur_sid = sub.read_sid(st, pkeys, r_slot)
                 s_i, c_i, iv_abort = postsi_bounds(
-                    s_lo[i], s_hi[i], c_lo[i], r_i, w_i, nv_cid, nv_sid,
-                    cur_sid, ongoing_reader, s_lo)
+                    s_lo, s_hi, c_lo, is_read, is_write, nv_cid, nv_sid,
+                    cur_sid, ongoing_readers(potential, status), s_lo)
                 abort = abort | iv_abort
             else:
-                # clocked baselines: snapshot = wave-entry clock; commit =
-                # clock++
-                s_i = clock0
-                c_i = clk + 1
+                # clocked baselines: snapshot = wave-entry clock
+                s_i = jnp.broadcast_to(clock0, (T,))
 
             # GC watermark consult (DESIGN.md §8): does any write reuse a
             # ring slot whose version is still visible above the watermark?
             if track_gc:
-                evict_unsafe = w_i & sub.evicting_visible(st, pk_i, wm)  # [O]
+                evict_unsafe = is_write & sub.evicting_visible(st, pkeys, wm)
             if gc_block:
                 # blocked install: abort instead of corrupting still-visible
                 # reads; retried once the watermark passes the superseder
-                abort = abort | evict_unsafe.any()
+                abort = abort | evict_unsafe.any(axis=-1)
 
-            commit = active & ~abort
-            new_status = jnp.where(active, jnp.where(abort, ABORTED,
-                                                     COMMITTED), status[i])
+            commit = seg & ~abort
+            if sched != "postsi":
+                # commit = clock++, committer by committer
+                c_i = clk + jnp.cumsum(commit, dtype=jnp.int32)
 
         # ---- install writes (masked scatter; owner/OOB handling is the
         # substrate's concern: sentinel-drop locally, owner-only on the mesh)
         with jax.named_scope("install"):
-            wmask = w_i & commit
-            val_new = jnp.where(wave.op_kind[i] == RMW,
-                                r_val[i] + wave.op_val[i], wave.op_val[i])
-            st = sub.install(st, wmask, pk_i, val_new, wave.tid[i], c_i,
-                             wave_idx)
-            wcid = wcid.at[i].set(jnp.where(wmask, c_i, -1))
+            wmask = is_write & commit[:, None]
+            c_b = jnp.broadcast_to(c_i[:, None], (T, O))
+            st = sub.install(st, wmask, pkeys, val_new, tid_b, c_b, wave_idx)
+            wcid = jnp.where(wmask, c_b, wcid)
 
         # ---- rule 4(c): bump SIDs of read versions to my start time --------
         # guarded: skip if the ring slot was recycled since our wave-start read
         with jax.named_scope("bump_sid"):
-            st = sub.bump_sid(st, r_i & commit, pk_i, r_slot[i], r_tid[i], s_i)
+            st = sub.bump_sid(st, is_read & commit[:, None], pkeys, r_slot,
+                              r_tid, jnp.broadcast_to(s_i[:, None], (T, O)))
 
         # ---- rule 4(b): push bounds of conflicting *ongoing* transactions --
         if sched == "postsi":
             with jax.named_scope("push_bounds"):
-                s_lo, s_hi, c_lo = push_bounds(i, commit, s_i, c_i, potential,
+                s_lo, s_hi, c_lo = push_bounds(commit, s_i, c_i, potential,
                                                status, s_lo, s_hi, c_lo)
 
         with jax.named_scope("record"):
-            status = status.at[i].set(new_status)
-            s_arr = s_arr.at[i].set(jnp.where(commit, s_i, -1))
-            c_arr = c_arr.at[i].set(jnp.where(commit, c_i, -1))
-            clk = jnp.where(commit, jnp.maximum(clk, c_i), clk)
+            status = jnp.where(seg, jnp.where(abort, ABORTED, COMMITTED),
+                               status)
+            s_arr = jnp.where(commit, s_i, s_arr)
+            c_arr = jnp.where(commit, c_i, c_arr)
+            clk = jnp.maximum(clk, jnp.where(commit, c_i, clk).max())
             if track_gc:
-                ev_cnt = ev_cnt + jnp.where(
-                    commit, evict_unsafe.astype(jnp.int32).sum(), 0)
-        return (st, s_lo, s_hi, c_lo, status, s_arr, c_arr, wcid, clk, ev_cnt)
+                ev_cnt = ev_cnt + jnp.where(commit[:, None], evict_unsafe,
+                                            False).sum(dtype=jnp.int32)
+            # the next segment starts where this one ends
+            with jax.named_scope("segment"):
+                a, b = b, segment_end(prev, b)
+        return (a, b, steps + 1, st, s_lo, s_hi, c_lo, status, s_arr, c_arr,
+                wcid, clk, ev_cnt)
 
     with jax.named_scope("commit_loop"):
         status0 = jnp.full((T,), RUNNING, jnp.int32)
@@ -274,11 +303,11 @@ def run_wave_on(sub, store: MVStore, wave: Wave, wave_idx: jax.Array,
         c0 = jnp.full((T,), -1, jnp.int32)
         wcid0 = jnp.full((T, O), -1, jnp.int32)
 
-        (store, s_lo, s_hi, c_lo, status, s_arr, c_arr, wcid, clock,
-         evicted) = lax.fori_loop(
-            0, T, commit_one,
-            (store, s_lo0, s_hi0, c_lo0, status0, s0, c0, wcid0, clock,
-             jnp.int32(0)))
+        (_, _, commit_steps, store, s_lo, s_hi, c_lo, status, s_arr, c_arr,
+         wcid, clock, evicted) = lax.while_loop(
+            lambda carry: carry[0] < T, commit_segment,
+            (jnp.int32(0), b0, jnp.int32(0), store, s_lo0, s_hi0, c_lo0,
+             status0, s0, c0, wcid0, clock, jnp.int32(0)))
 
         write_key = jnp.where(is_write & (status[:, None] == COMMITTED), keys, -1)
 
@@ -337,7 +366,7 @@ def run_wave_on(sub, store: MVStore, wave: Wave, wave_idx: jax.Array,
             waits = jnp.maximum(node_skew - my_skew, 0).sum(where=remote_op & is_read)
 
     out = WaveOut(status, s_arr, c_arr, read_key, read_cid, write_key, wcid,
-                  msgs_cross, msgs_coord, waits, evicted)
+                  msgs_cross, msgs_coord, waits, evicted, commit_steps)
     return store, out, clock
 
 

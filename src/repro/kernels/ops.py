@@ -112,48 +112,87 @@ def sid_regather(sid, keys, slots):
     return sid[keys, slots]
 
 
+# The install and the SID bump write only their packed entries, in loops;
+# each is jitted so that the operations the compiler adds to those loops
+# take the caller's named scope, as every other operation of the commit loop.
+
+# updates per scatter into an ``[n_keys, V]`` ring: from 1024 on, XLA's TPU
+# scatter relays the whole ring out through temporaries
+_SCATTER_ROWS = 512
+# single-element ``head``/``wave`` writes per step of the install's tag loop
+_TAG_WRITES = 8
+
+
+def _packed(m):
+    """(count, indices) of the set entries of the flat mask ``m``, packed
+    to the front in order."""
+    pos = jnp.where(m, jnp.cumsum(m) - 1, m.size)
+    order = jnp.zeros(m.shape, jnp.int32).at[pos].set(
+        jnp.arange(m.size, dtype=jnp.int32), mode="drop")
+    return m.sum(dtype=jnp.int32), order
+
+
+def _over_packed(n, order, width, body, carry):
+    """Fold ``body(idx, carry)`` over the first ``n`` packed indices,
+    ``width`` at a time.  A short last chunk repeats its last index, so
+    ``body`` must leave an entry applied twice as applied once."""
+    width = min(width, order.shape[0])
+    lanes = jnp.arange(width, dtype=jnp.int32)
+
+    def chunk(c, carry):
+        return body(order[jnp.minimum(c * width + lanes, n - 1)], carry)
+
+    return lax.fori_loop(0, (n + width - 1) // width, chunk, carry)
+
+
+@jax.jit
 def masked_install(val, tid, cid, sid, head, wave, *, mask, keys, values,
                    new_tid, new_cid, wave_idx):
     """Masked version install over a key batch (rule 4(c) CID stamping).
 
     Pushes a new ring version for every key with ``mask`` set: the slot after
     ``head`` is overwritten, SID resets to 0, ``head``/``wave`` advance.
-    Keys are clamped before the ``head`` gather so masked/NOP keys (which may
-    be negative padding) can never wrap to a real key; a key with ``mask``
-    set must be in range.  Returns the six updated ring arrays.
+    ``values``, ``new_tid`` and ``new_cid`` broadcast to ``keys``.  Keys are
+    clamped before the ``head`` gather so masked/NOP keys (which may be
+    negative padding) can never wrap to a real key; a key with ``mask`` set
+    must be in range.  Where several ops with ``mask`` set hit one key, the
+    last of them (in row-major order) is installed, as a scatter that applies
+    its updates in order leaves it.  Returns the six updated ring arrays.
 
-    The four ``[n_keys, V]`` rings are written by one scatter each, with
-    masked-off rows routed to an OOB sentinel and dropped.  ``head`` and
-    ``wave`` are written by one single-element in-place update per op
-    instead: on a TPU a scatter into a 1-D ``[n_keys]`` array stages the
-    whole array in scoped memory wherever it fits there (at 3M keys, not at
-    2^23), a full read and write of it per call, while a
-    ``dynamic_update_slice`` touches one element.  Each op writes its
-    clamped key's final tag: the new one where any op with ``mask`` set
-    hits that key, else the one it had.  So masked-off ops and NOP keys
-    change nothing, and duplicate keys end as the scatter leaves them
-    (every op on one key carries the same ``h_new`` and ``wave_idx``).
-    The final tags are computed before the writes, so the writes are
-    independent of one another and need no read of the array between them.
+    Only the installed ops are written, packed to the front: the four
+    ``[n_keys, V]`` rings by scatters of at most ``_SCATTER_ROWS`` updates,
+    ``head`` and ``wave`` by single-element in-place updates,
+    ``_TAG_WRITES`` per loop step.  On a TPU a scatter into a 1-D
+    ``[n_keys]`` array stages the whole array in scoped memory wherever it
+    fits there (at 3M keys, not at 2^23), a full read and write of it per
+    call, while a ``dynamic_update_slice`` touches one element.
     """
     n_keys, n_versions = val.shape
-    k = jnp.clip(keys, 0, n_keys - 1)
-    h_cur = head[k]
-    h_new = (h_cur + 1) % n_versions
-    k_install = jnp.where(mask, keys, n_keys)
-    rows = (val.at[k_install, h_new].set(values, mode="drop"),
-            tid.at[k_install, h_new].set(new_tid, mode="drop"),
-            cid.at[k_install, h_new].set(new_cid, mode="drop"),
-            sid.at[k_install, h_new].set(0, mode="drop"))
-    k, m = k.ravel(), jnp.broadcast_to(mask, keys.shape).ravel()
-    hit = ((k[:, None] == k[None, :]) & m[None, :]).any(axis=1)
-    head_fin = jnp.where(hit, h_new.ravel(), h_cur.ravel())
-    wave_fin = jnp.where(hit, jnp.broadcast_to(wave_idx, keys.shape).ravel(),
-                         wave[k]).astype(wave.dtype)
-    for j in range(k.shape[0]):
-        head = _put(head, head_fin[j], k[j])
-        wave = _put(wave, wave_fin[j], k[j])
-    return rows + (head, wave)
+    flat = lambda x: jnp.broadcast_to(x, keys.shape).ravel()
+    k = jnp.clip(keys, 0, n_keys - 1).ravel()
+    m = flat(mask)
+    op = jnp.arange(k.size)
+    m = m & ~((k[:, None] == k[None, :]) & m[None, :]
+              & (op[None, :] > op[:, None])).any(axis=1)
+    h_new = (head[k] + 1) % n_versions
+    new = (flat(values), flat(new_tid), flat(new_cid), jnp.zeros_like(k))
+    w_new = jnp.asarray(wave_idx, wave.dtype)
+    n, order = _packed(m)
+
+    def rings(idx, rows):
+        return tuple(r.at[k[idx], h_new[idx]].set(x[idx])
+                     for r, x in zip(rows, new))
+
+    def tags(idx, tags):
+        head, wave = tags
+        k_j, h_j = k[idx], h_new[idx]
+        for j in range(idx.shape[0]):
+            head = _put(head, h_j[j], k_j[j])
+            wave = _put(wave, w_new, k_j[j])
+        return head, wave
+
+    rows = _over_packed(n, order, _SCATTER_ROWS, rings, (val, tid, cid, sid))
+    return rows + _over_packed(n, order, _TAG_WRITES, tags, (head, wave))
 
 
 def _put(a, x, i):
@@ -162,14 +201,21 @@ def _put(a, x, i):
                                     allow_negative_indices=False)
 
 
+@jax.jit
 def masked_sid_bump(sid, tid, *, mask, keys, slots, expect_tid, s_val):
     """Rule-4(c) SID bump over a key batch: raise the SID of read versions to
     the reader's start time, guarded against ring slots recycled since the
-    read (creator TID must still match).  Returns the updated sid array."""
-    n_keys = sid.shape[0]
-    ok = mask & (tid[keys, slots] == expect_tid)
-    k_sid = jnp.where(ok, keys, n_keys)
-    return sid.at[k_sid, slots].max(s_val, mode="drop")
+    read (creator TID must still match).  ``expect_tid`` and ``s_val``
+    broadcast to ``keys``; only the bumped entries are scattered, packed to
+    the front, at most ``_SCATTER_ROWS`` a scatter.  Returns the updated sid
+    array."""
+    flat = lambda x: jnp.broadcast_to(x, keys.shape).ravel()
+    ok = flat(mask & (tid[keys, slots] == expect_tid))
+    k, slot, s = flat(keys), flat(slots), flat(s_val)
+    n, order = _packed(ok)
+    return _over_packed(
+        n, order, _SCATTER_ROWS,
+        lambda idx, sid: sid.at[k[idx], slot[idx]].max(s[idx]), sid)
 
 
 # ---------------------------------------------------------------------------

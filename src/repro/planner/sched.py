@@ -62,7 +62,8 @@ ALL_SCHEDULERS = SCHEDULERS + (PLANNED,)
 #: disables spilling entirely (lane count = longest conflict chain + 1)
 DEFAULT_MAX_LANES = 16
 
-_STAT_FIELDS = ("msgs_cross", "msgs_coord", "waits", "evicted_visible")
+_STAT_FIELDS = ("msgs_cross", "msgs_coord", "waits", "evicted_visible",
+                "commit_steps")
 
 
 class PlannerError(RuntimeError):

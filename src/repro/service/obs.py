@@ -19,6 +19,12 @@ Stages of ``StreamingDriver`` and ``TxnService``:
     step         one tick of the synchronous step loop
     submit       ``TxnService.submit``; ``record`` only, never a span, as
                  it runs once per request
+
+and one count with no host time of its own (``count``, never a span):
+
+    commit_step  the commit-loop steps of each routed wave
+                 (``WaveOut.commit_steps``: one per segment of key-disjoint
+                 transactions)
 """
 from __future__ import annotations
 
@@ -33,6 +39,13 @@ def record(svc, name: str, t0: float) -> None:
     """Add the seconds since ``t0`` and one count to stage ``name``."""
     svc.stage_s[name] += perf_counter() - t0
     svc.stage_n[name] += 1
+
+
+def count(svc, name: str, n: int) -> None:
+    """Add ``n`` to count ``name``; its seconds stay 0, so both dicts keep
+    one key per name."""
+    svc.stage_s[name] += 0.0
+    svc.stage_n[name] += n
 
 
 class stage:

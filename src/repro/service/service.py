@@ -42,7 +42,7 @@ from repro.placement import (HotKeyReplicas, LoadBalancer, apply_move,
 
 from .former import TxnRequest, WaveFormer, fold_counts
 from .gc import VisibilityGC
-from .obs import record, stage
+from .obs import count, record, stage
 from .retry import RetryPolicy
 
 
@@ -406,8 +406,10 @@ class TxnService:
         request exactly once: on commit all members commit with the row's
         (s, c) — the summed delta IS their serial net effect — and on abort
         each member re-enters the retry calendar individually (it may fold
-        into a different group next wave)."""
+        into a different group next wave).  The wave's commit-loop steps
+        add to the ``commit_step`` count (service/obs.py)."""
         t_ack = time.perf_counter()
+        count(self, "commit_step", int(out.commit_steps))
         for i, req in enumerate(slots):
             group = (req, *req.folded)
             req.folded = []

@@ -81,7 +81,7 @@ def test_a_trace_gets_one_span_per_stage_but_submit(annotation):
     assert spans == ["repro." + s for s in ("dispatch", "flush", "form",
                                             "retire_wait", "route", "tick")]
     assert len(annotation.built) == sum(
-        v for k, v in svc.stage_n.items() if k != "submit")
+        v for k, v in svc.stage_n.items() if k not in ("submit", "commit_step"))
 
 
 def test_stages_land_on_the_profilers_host_plane(tmp_path):

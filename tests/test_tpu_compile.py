@@ -94,8 +94,8 @@ def _store(sh, n_keys=N_KEYS):
     return MVStore(*([_i32((n_keys, V), sh)] * 4 + [_i32((n_keys,), sh)] * 2))
 
 
-def _block(sh):
-    return Wave(*([_i32((B, T, O_SERVED), sh)] * 3 + [_i32((B, T), sh)] * 2))
+def _block(sh, t=T):
+    return Wave(*([_i32((B, t, O_SERVED), sh)] * 3 + [_i32((B, t), sh)] * 2))
 
 
 def _fits_hbm(compiled):
@@ -146,6 +146,29 @@ def test_commit_loop_stages_no_whole_key_array(one_chip, kernels):
     assert scoped, "no commit-loop op found in the compiled text"
     staged = [op for size, op in scoped if size >= 4 * N_SERVED]
     assert not staged, f"whole-array staging in the commit loop: {staged}"
+
+
+def _commit_loop_ops(compiled):
+    """Compiled operations whose ``op_name`` lies under ``commit_loop``."""
+    return sum(1 for line in compiled.as_text().splitlines()
+               if re.search(r'^\s*(ROOT )?%\S+ = .*op_name="[^"]*/commit_loop/',
+                            line))
+
+
+@pytest.mark.parametrize("kernels", ["pallas", "pallas+fused"])
+def test_commit_loop_ops_do_not_grow_with_wave_width(one_chip, kernels):
+    """The commit loop is one step body over the lanes of a segment, so the
+    block program at the benchmark's store size compiles to as many
+    commit-loop operations at T=256 as at T=128 (within 5% for the
+    compiler's own choices); an unrolled per-transaction op would double
+    them."""
+    scalar = _i32((), one_chip)
+    ops = {t: _commit_loop_ops(engine._scan_block.lower(
+        _store(one_chip, N_SERVED), _block(one_chip, t), scalar, scalar,
+        scalar, None, None, sched="postsi", gc_track=True,
+        kernels=KernelConfig(kernels)).compile()) for t in (128, 256)}
+    assert ops[128] > 0
+    assert ops[256] <= 1.05 * ops[128], ops
 
 
 @pytest.mark.parametrize("kernels", ["pallas", "pallas+fused"])
